@@ -35,6 +35,7 @@ from repro.core import cyclic3, engine, linear3, plan_ir, star3  # noqa: E402
 from repro.core.query import Query  # noqa: E402
 from repro.core.relation import Relation  # noqa: E402
 from repro.core.session import JoinSession  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.perfmodel import Calibration, calibrate  # noqa: E402
 
 OUT = pathlib.Path("BENCH_engine.json")
@@ -396,6 +397,7 @@ def main():
                     help="CI sizes (smaller relations, fewer repeats)")
     ap.add_argument("--repeats", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     repeats = args.repeats or (2 if args.quick else 4)
     scale = 1 if args.quick else 2

@@ -1,16 +1,4 @@
-"""JAX cross-version compatibility shims.
-
-The repo targets the jax >= 0.4.37 line; a few APIs moved between 0.4.x and
-0.5+/0.6+:
-
-* ``shard_map`` graduated from ``jax.experimental.shard_map`` to ``jax``
-  proper, renaming ``check_rep`` → ``check_vma`` on the way,
-* ``jax.sharding.AxisType`` (explicit-sharding mesh axis types) only exists
-  on newer jax; older versions are implicitly "auto" everywhere.
-
-Everything here degrades gracefully so a single codebase runs on either
-line (CI pins one, accelerator images may carry another).
-"""
+"""Thin wrappers over the two JAX mesh APIs the repo uses (jax 0.9.0)."""
 
 from __future__ import annotations
 
@@ -18,19 +6,12 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """`jax.shard_map` with replication checking off, on any jax line."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """`jax.shard_map` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` with Auto axis types where the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -34,8 +34,8 @@ histogram as the parity oracle.
 
 from __future__ import annotations
 
-import functools
 import math
+import warnings
 from typing import NamedTuple
 
 import jax
@@ -92,7 +92,7 @@ def _device_count(build: Relation, probe: Relation, *, build_key: str,
                   probe_key: str):
     """Sorted-key histogram count: per-probe segment counts + exact
     two-limb reduction, all on device."""
-    _, skeys = partition.sort_by_key(build, build_key)
+    _, skeys = partition.key_order(build, build_key)
     lo, hi = match_ranges(skeys, probe.col(probe_key))
     cnt = jnp.where(probe.valid, hi - lo, 0).astype(jnp.int32)
     return _sum64(cnt)
@@ -129,7 +129,7 @@ def match_ranges(sorted_keys: jnp.ndarray, probe_keys: jnp.ndarray):
 def join_count(build: Relation, build_key: str,
                probe: Relation, probe_key: str) -> jnp.ndarray:
     """Exact number of matching (build, probe) pairs."""
-    _, skeys = partition.sort_by_key(build, build_key)
+    _, skeys = partition.key_order(build, build_key)
     lo, hi = match_ranges(skeys, probe.col(probe_key))
     cnt = jnp.where(probe.valid, hi - lo, 0)
     return jnp.sum(cnt.astype(jnp.int64) if cnt.dtype == jnp.int64
@@ -143,10 +143,7 @@ def probe_weight_sum(build: Relation, build_key: str, build_weights: jnp.ndarray
     The workhorse for per-key multiway aggregates: weights flow backwards
     through each join stage (T -> S -> R) without materializing anything.
     """
-    srel, skeys = partition.sort_by_key(build, build_key)
-    # weights must be permuted identically to the sort; recompute the order.
-    keys = jnp.where(build.valid, build.col(build_key), jnp.int32(0x7FFFFFFF))
-    order = jnp.argsort(keys, stable=True)
+    order, skeys = partition.key_order(build, build_key)
     w = jnp.where(build.valid, build_weights, 0)[order]
     cw = jnp.concatenate([jnp.zeros((1,), w.dtype), jnp.cumsum(w)])
     lo, hi = match_ranges(skeys, probe_keys)
@@ -213,16 +210,25 @@ class StagedJoin(NamedTuple):
     total_lo: jnp.ndarray      # () int32, < 2^30
 
 
-def _stage_core(build: Relation, probe: Relation, *, build_key: str,
-                probe_key: str) -> StagedJoin:
-    sbuild, skeys = partition.sort_by_key(build, build_key)
+def _stage_core(build: Relation, order: jnp.ndarray, skeys: jnp.ndarray,
+                probe: Relation, *, probe_key: str) -> StagedJoin:
+    sbuild = build.select(order, jnp.ones_like(order, dtype=bool))
     lo, hi = match_ranges(skeys, probe.col(probe_key))
     cnt = jnp.where(probe.valid, hi - lo, 0).astype(jnp.int32)
     thi, tlo = _sum64(cnt)
     return StagedJoin(sbuild, lo, cnt, thi, tlo)
 
 
-stage_join = jax.jit(_stage_core, static_argnames=("build_key", "probe_key"))
+_stage_jit = jax.jit(_stage_core, static_argnames=("probe_key",))
+
+
+def stage_join(build: Relation, probe: Relation, *, build_key: str,
+               probe_key: str) -> StagedJoin:
+    """Stage 1 of a binary step: sort the build side by its key (the shared
+    ``partition.stable_order`` program of its capacity), then match ranges
+    and the exact total in one jitted dispatch."""
+    order, skeys = partition.key_order(build, build_key)
+    return _stage_jit(build, order, skeys, probe, probe_key=probe_key)
 
 
 def staged_total(staged: StagedJoin) -> int:
@@ -264,27 +270,29 @@ def _gather_core(sorted_build: Relation, lo: jnp.ndarray, cnt: jnp.ndarray,
     return Relation(cols, ok)
 
 
-@functools.lru_cache(maxsize=None)
-def _gather_jit(donate: bool):
-    statics = ("out_capacity", "build_prefix", "probe_prefix")
-    if donate:
-        # the staged buffers are consumed here; donating them lets XLA
-        # reuse the sorted-build storage for the materialized output
-        return jax.jit(_gather_core, static_argnames=statics,
-                       donate_argnums=(0, 1, 2))
-    return jax.jit(_gather_core, static_argnames=statics)
+# the staged buffers are consumed here; donating them lets XLA reuse the
+# sorted-build storage for the materialized output
+_gather_jit = jax.jit(_gather_core,
+                      static_argnames=("out_capacity", "build_prefix",
+                                       "probe_prefix"),
+                      donate_argnums=(0, 1, 2))
 
 
 def gather_staged(staged: StagedJoin, probe: Relation, out_capacity: int,
                   *, build_prefix: str = "",
                   probe_prefix: str = "") -> Relation:
-    """Finish a staged materialize: one jitted dispatch, donated staged
-    buffers on backends that support donation (CPU does not)."""
-    donate = jax.default_backend() != "cpu"
-    return _gather_jit(donate)(
-        staged.sorted_build, staged.lo, staged.cnt, probe,
-        out_capacity=out_capacity, build_prefix=build_prefix,
-        probe_prefix=probe_prefix)
+    """Finish a staged materialize: one jitted dispatch that donates (and
+    so deletes) the staged buffers — ``staged`` is dead afterwards.  XLA
+    reuses only buffers shaped like an output (a chained pow2
+    intermediate); the rest are freed with ``staged``, so the lowering's
+    "not usable" warning is expected and silenced."""
+    with warnings.catch_warnings():
+        warnings.filterwarnings("ignore", "Some donated buffers were not "
+                                "usable")
+        return _gather_jit(
+            staged.sorted_build, staged.lo, staged.cnt, probe,
+            out_capacity=out_capacity, build_prefix=build_prefix,
+            probe_prefix=probe_prefix)
 
 
 # --------------------------------------------------------------------------

@@ -16,12 +16,14 @@ Two static-shape-friendly layouts are provided:
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import hashing
-from repro.core.relation import SENTINEL, Relation, sentinel_fill
+from repro.core.relation import SENTINEL, Relation
 
 _INT32_MAX = 2**31 - 1
 
@@ -88,65 +90,71 @@ def partition_sorted2(rel: Relation, outer_col: str, inner_col: str,
 def bucketize(rel: Relation, key_col: str, n_buckets: int, capacity: int,
               fn: str = "h", salt: int = 0,
               sentinel: int = SENTINEL) -> Buckets:
-    """Scatter rows into a fixed [n_buckets, capacity] grid.
+    """Scatter rows into a fixed [n_buckets, capacity] grid by the hash of
+    ``key_col`` (the one-level :func:`bucketize_by_ids`).
 
     Rows beyond a bucket's capacity are dropped and flagged via
     ``overflowed`` — the caller must re-partition (bigger capacity or new
-    salt).  Implementation: rank-within-bucket via a stable sort, then a
-    single scatter; O(n log n), no dynamic shapes.
+    salt).
     """
-    _check_flat_range(n_buckets * capacity + 1, "n_buckets * capacity")
-    ids = bucket_ids_for(rel, key_col, n_buckets, fn, salt)
-    order = jnp.argsort(ids, stable=True)
-    sorted_ids = ids[order]
-    # position of each sorted row within its bucket
-    starts = jnp.searchsorted(sorted_ids, jnp.arange(n_buckets + 1), side="left")
-    within = jnp.arange(sorted_ids.shape[0]) - starts[jnp.clip(sorted_ids, 0, n_buckets)]
-    counts = (starts[1:] - starts[:-1]).astype(jnp.int32)
-    overflowed = jnp.any(counts > capacity)
+    ids = _bucket_ids(rel, key_col, n_buckets, fn, salt)
+    return bucketize_by_ids(rel, ids, n_buckets, capacity, (n_buckets,),
+                            sentinel)
 
-    keep = (sorted_ids < n_buckets) & (within < capacity)
-    dest = jnp.where(keep, sorted_ids * capacity + within, n_buckets * capacity)
 
-    filled = sentinel_fill(rel, sentinel)
-    out_cols = {}
-    for name, col in filled.columns.items():
-        flat = jnp.full((n_buckets * capacity + 1,), sentinel, dtype=jnp.int32)
-        flat = flat.at[dest].set(col[order], mode="drop")
-        out_cols[name] = flat[:-1].reshape(n_buckets, capacity)
-    vflat = jnp.zeros((n_buckets * capacity + 1,), dtype=bool)
-    vflat = vflat.at[dest].set(rel.valid[order], mode="drop")
-    valid = vflat[:-1].reshape(n_buckets, capacity)
-    return Buckets(out_cols, valid, counts, overflowed)
+_bucket_ids = jax.jit(bucket_ids_for,
+                      static_argnames=("key_col", "n_buckets", "fn", "salt"))
+
+
+@jax.jit
+def stable_order(keys: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Stable ascending argsort of ``keys`` and the sorted keys.
+
+    Its own program, keyed only by the key array's shape: a TPU sort of
+    ~10^5 keys or more takes 15-20 s to compile, so every layout, recovery
+    round and binary step over relations of one capacity shares this one
+    compiled sort, and the programs around it compile in about a second.
+    """
+    order = jnp.argsort(keys, stable=True)
+    return order, keys[order]
 
 
 def bucketize_by_ids(rel: Relation, flat_ids: jnp.ndarray, n_buckets: int,
                      capacity: int, out_shape: tuple,
                      sentinel: int = SENTINEL) -> Buckets:
-    """Scatter rows into `[*out_shape, capacity]` by precomputed flat bucket
+    """Lay rows out as `[*out_shape, capacity]` by precomputed flat bucket
     ids (invalid rows must carry id == n_buckets).  Generic engine behind the
-    composite two/three-level layouts of Fig 2/3."""
+    composite two/three-level layouts of Fig 2/3.  Implementation: a stable
+    sort groups each bucket's rows in arrival order, then slot j of bucket b
+    gathers the bucket's j-th row; O(n log n), no dynamic shapes."""
     _check_flat_range(n_buckets * capacity + 1, "n_buckets * capacity")
-    order = jnp.argsort(flat_ids, stable=True)
-    sorted_ids = flat_ids[order]
-    starts = jnp.searchsorted(sorted_ids, jnp.arange(n_buckets + 1), side="left")
-    within = jnp.arange(sorted_ids.shape[0]) - starts[
-        jnp.clip(sorted_ids, 0, n_buckets)]
-    counts = (starts[1:] - starts[:-1]).astype(jnp.int32)
+    order, sorted_ids = stable_order(flat_ids)
+    return _gather_buckets(rel, order, sorted_ids, n_buckets=n_buckets,
+                           capacity=capacity, out_shape=tuple(out_shape),
+                           sentinel=sentinel)
+
+
+@functools.partial(jax.jit, static_argnames=("n_buckets", "capacity",
+                                             "out_shape", "sentinel"))
+def _gather_buckets(rel: Relation, order: jnp.ndarray,
+                    sorted_ids: jnp.ndarray, *, n_buckets: int,
+                    capacity: int, out_shape: tuple,
+                    sentinel: int) -> Buckets:
+    # a gather, not a scatter: a TPU scatter of a bool plane alone takes
+    # ~10 s to compile, the gather well under one
+    starts = jnp.searchsorted(sorted_ids, jnp.arange(n_buckets + 1),
+                              side="left").astype(jnp.int32)
+    counts = starts[1:] - starts[:-1]
     overflowed = jnp.any(counts > capacity)
-    keep = (sorted_ids < n_buckets) & (within < capacity)
-    dest = jnp.where(keep, sorted_ids * capacity + within, n_buckets * capacity)
-    cols = {}
-    for name, col in rel.columns.items():
-        flat = jnp.full((n_buckets * capacity + 1,), sentinel, dtype=jnp.int32)
-        flat = flat.at[dest].set(jnp.where(rel.valid, col,
-                                           jnp.int32(sentinel))[order],
-                                 mode="drop")
-        cols[name] = flat[:-1].reshape(*out_shape, capacity)
-    vflat = jnp.zeros((n_buckets * capacity + 1,), dtype=bool)
-    vflat = vflat.at[dest].set(rel.valid[order], mode="drop")
-    valid = vflat[:-1].reshape(*out_shape, capacity)
-    return Buckets(cols, valid, counts.reshape(out_shape), overflowed)
+    slot = jnp.arange(capacity, dtype=jnp.int32)
+    valid = slot[None, :] < counts[:, None]          # rows beyond cap drop
+    rows = order[jnp.clip(starts[:-1, None] + slot[None, :], 0,
+                          sorted_ids.shape[0] - 1)]
+    shape = (*out_shape, capacity)
+    cols = {name: jnp.where(valid, col[rows], jnp.int32(sentinel)).reshape(shape)
+            for name, col in rel.columns.items()}
+    return Buckets(cols, valid.reshape(shape), counts.reshape(out_shape),
+                   overflowed)
 
 
 def composite_ids(rel: Relation, specs: list[tuple[str, int, str]],
@@ -160,15 +168,21 @@ def composite_ids(rel: Relation, specs: list[tuple[str, int, str]],
     cyclic four-level layout on a huge plan) would otherwise wrap silently
     and scatter rows into wrong buckets.
     """
+    specs = tuple(tuple(spec) for spec in specs)
     total = 1
     for _col, nb, _fn in specs:
         total *= nb
     _check_flat_range(total, f"prod(n_buckets) for specs {specs!r}")
+    return _composite_ids(rel, specs, salt, total), total
+
+
+@functools.partial(jax.jit, static_argnames=("specs", "salt", "total"))
+def _composite_ids(rel: Relation, specs, salt: int, total: int):
     flat = jnp.zeros((rel.capacity,), jnp.int32)
     for col, nb, fn in specs:
         ids = bucket_ids_for(rel, col, nb, fn, salt)
         flat = flat * nb + jnp.clip(ids, 0, nb - 1)
-    return jnp.where(rel.valid, flat, jnp.int32(total)), total
+    return jnp.where(rel.valid, flat, jnp.int32(total))
 
 
 def suggest_capacity(n_rows: int, n_buckets: int, slack: float = 2.0,
@@ -182,11 +196,18 @@ def suggest_capacity(n_rows: int, n_buckets: int, slack: float = 2.0,
     return int(math.ceil(cap / align) * align)
 
 
+def key_order(rel: Relation, key_col: str,
+              big: int = 0x7FFFFFFF) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Stable row order of ``rel`` by the *actual* key (invalid rows last,
+    as the ``big`` sentinel) and the sorted keys, via :func:`stable_order`."""
+    return stable_order(jnp.where(rel.valid, rel.col(key_col),
+                                  jnp.int32(big)))
+
+
 def sort_by_key(rel: Relation, key_col: str,
                 big: int = 0x7FFFFFFF) -> tuple[Relation, jnp.ndarray]:
     """Sort rows by the *actual* key (invalid rows last).  Returns the sorted
     relation and the sorted key array (invalid = big sentinel) for
     searchsorted probes — the exact-join building block."""
-    keys = jnp.where(rel.valid, rel.col(key_col), jnp.int32(big))
-    order = jnp.argsort(keys, stable=True)
-    return rel.select(order, jnp.ones_like(order, dtype=bool)), keys[order]
+    order, skeys = key_order(rel, key_col, big)
+    return rel.select(order, jnp.ones_like(order, dtype=bool)), skeys

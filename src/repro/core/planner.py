@@ -245,6 +245,19 @@ def _swap_linear_rt(cls_: Classification) -> Classification:
               ("sc", cm["sb"]), ("tc", cm["rb"])))
 
 
+def _cascade3_plan(cls_: Classification, n_r: int, n_t: int,
+                   cfg: dict) -> plan_ir.QueryPlan:
+    """The 2-step cascade over a linear/star path, materializing the join
+    of S with the SMALLER endpoint: the path is symmetric, and I = R ⋈ S
+    with |R| >> |T| (e.g. a standing query's delta in T's slot) would
+    materialize |R|·|S|/d rows where |S|·|T|/d suffice."""
+    if n_t < n_r:
+        cls_ = _swap_linear_rt(cls_)
+    return plan_ir.QueryPlan(
+        steps=_cascade3_steps(dict(cls_.roles), dict(cls_.cols)),
+        n_relations=3, kind=cls_.kind, strategy="cascade", **cfg)
+
+
 def pin_per_r_classification(cls_: Classification,
                              per_r_name: str) -> Classification:
     """Validate + adjust a 3-relation classification so a pinned per-R
@@ -478,9 +491,7 @@ def plan_query(query: Query, cards=None, *, m_budget: int | None = None,
             if cls_.kind == "cyclic":
                 raise ValueError("the cyclic (triangle) query has no "
                                  "2-join binary cascade")
-            return plan_ir.QueryPlan(
-                steps=_cascade3_steps(role_map, dict(cls_.cols)),
-                n_relations=3, kind=cls_.kind, strategy="cascade", **cfg)
+            return _cascade3_plan(cls_, n_r, n_t, cfg)
         if strategy == "3way":
             if cls_.kind != "star" and m_budget is None:
                 raise ValueError(f"{cls_.kind} plans need m_budget")
@@ -495,9 +506,7 @@ def plan_query(query: Query, cards=None, *, m_budget: int | None = None,
             return _single_fused_plan(query, cls_, ep,
                                       per_r_key=(per_r_key if per_r_name
                                                  else None))
-        return plan_ir.QueryPlan(
-            steps=_cascade3_steps(role_map, dict(cls_.cols)),
-            n_relations=3, kind=cls_.kind, strategy="cascade", **cfg)
+        return _cascade3_plan(cls_, n_r, n_t, cfg)
 
     # ---- N >= 4: acyclic (tree) decomposition ---------------------------
     if classification is not None:

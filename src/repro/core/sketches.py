@@ -54,6 +54,7 @@ def key_bits(keys: jnp.ndarray, reg: int) -> jnp.ndarray:
     return (jnp.uint32(1) << shift).astype(jnp.int32)
 
 
+@jax.jit
 def add(registers: jnp.ndarray, keys: jnp.ndarray,
         valid: jnp.ndarray) -> jnp.ndarray:
     """Fold a batch of keys into the sketch."""

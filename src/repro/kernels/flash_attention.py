@@ -42,14 +42,9 @@ NEG_INF = -2.0e38
 
 
 def _load4(ref, h, start, size):
-    """Load ref[0, h, start:start+size, :] as a [size, D] block.
-
-    All four indices are Slice objects (size-1 slices squeezed afterwards):
-    older jax pallas (0.4.x) rejects plain ints mixed into a pl.load index
-    tuple, and ``h`` is dynamic in the dkv kernel anyway.
-    """
-    return pl.load(ref, (pl.dslice(0, 1), pl.dslice(h, 1),
-                         pl.dslice(start, size), slice(None)))[0, 0]
+    """Load ref[0, h, start:start+size, :] as a [size, D] block (``h`` is
+    dynamic in the dkv kernel; size-1 slices are squeezed afterwards)."""
+    return ref[pl.ds(0, 1), pl.ds(h, 1), pl.ds(start, size), :][0, 0]
 
 
 # --------------------------------------------------------------------------

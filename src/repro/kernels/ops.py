@@ -3,7 +3,8 @@
 Responsibilities kept out of the kernels so they stay branch-free:
   * sentinel-mask invalid slots with per-side sentinels (so invalid slots can
     never equal anything on the other side),
-  * pad capacities to 128-lane multiples (MXU/VPU alignment),
+  * pad capacities to 128-lane multiples (MXU/VPU alignment) and the fused
+    kernels' tiled PMU axis to a multiple of 8 rows (Mosaic's block rule),
   * dispatch kernel vs. pure-jnp reference (``use_kernel=False`` is the CPU
     default — interpret-mode Pallas is for validation, not speed),
   * cast/clip results back to caller shapes.
@@ -15,6 +16,7 @@ relational layer guarantee int32 keys ≥ -2^30.
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -44,17 +46,37 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _scan_kernel_gate() -> bool:
+    """Interpret flag for the per-bucket scan-driver kernels.  Their one-row
+    BlockSpecs do not meet Mosaic's (8, 128) block rule, so they run only
+    interpreted; on TPU the fused kernels are the compiled path."""
+    if not _interpret():
+        raise NotImplementedError(
+            "the per-bucket scan-driver kernels (pair_count / count3_* / "
+            "per_r_counts) are interpret-only; on TPU use the fused engine "
+            "(engine.MultiwayJoinEngine / JoinSession) or use_kernel=False")
+    return True
+
+
 def _mask(keys: jnp.ndarray, valid: jnp.ndarray, side: str) -> jnp.ndarray:
     return jnp.where(valid, keys, jnp.int32(_SENT[side]))
 
 
-def _pad_lanes(x: jnp.ndarray, side: str, align: int = 128) -> jnp.ndarray:
-    c = x.shape[-1]
-    rem = (-c) % align
+def _pad_lanes(x: jnp.ndarray, side: str, align: int = 128,
+               axis: int = -1) -> jnp.ndarray:
+    axis = axis % x.ndim
+    rem = (-x.shape[axis]) % align
     if rem == 0:
         return x
-    pad = [(0, 0)] * (x.ndim - 1) + [(0, rem)]
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, rem)
     return jnp.pad(x, pad, constant_values=_SENT[side])
+
+
+def _pad_tile(x: jnp.ndarray, side: str, axis: int) -> jnp.ndarray:
+    """Pad capacities to 128 lanes and the fused kernels' tiled PMU axis to
+    a multiple of ``bucket_join.TILE`` sentinel rows."""
+    return _pad_lanes(_pad_lanes(x, side), side, bucket_join.TILE, axis)
 
 
 def bucket_pair_count(ka, va, kb, vb, *, use_kernel: bool = False):
@@ -62,7 +84,7 @@ def bucket_pair_count(ka, va, kb, vb, *, use_kernel: bool = False):
     kb = _mask(kb, vb, "b")
     if use_kernel:
         return bucket_join.pair_count(_pad_lanes(ka, "a"), _pad_lanes(kb, "b"),
-                                      interpret=_interpret())
+                                      interpret=_scan_kernel_gate())
     return ref.bucket_pair_count(ka, kb)
 
 
@@ -75,7 +97,7 @@ def bucket_count3_linear(rb, rv, sb, sc, sv, tc, tv, *,
     if use_kernel:
         return bucket_join.count3_linear(
             _pad_lanes(rb, "r"), _pad_lanes(sb, "s"), _pad_lanes(sc, "s"),
-            _pad_lanes(tc, "t"), interpret=_interpret())
+            _pad_lanes(tc, "t"), interpret=_scan_kernel_gate())
     return ref.bucket_count3_linear(rb, sb, sc, tc)
 
 
@@ -89,7 +111,7 @@ def bucket_per_r_counts(rb, rv, sb, sc, sv, tc, tv, *,
     if use_kernel:
         out = bucket_join.per_r_counts(
             _pad_lanes(rb, "r"), _pad_lanes(sb, "s"), _pad_lanes(sc, "s"),
-            _pad_lanes(tc, "t"), interpret=_interpret())
+            _pad_lanes(tc, "t"), interpret=_scan_kernel_gate())
         return out[:, :cr]
     return ref.bucket_per_r_counts(rb, sb, sc, tc)
 
@@ -106,7 +128,7 @@ def bucket_count3_cyclic(ra, rb, rv, sb, sc, sv, tc, ta, tv, *,
         return bucket_join.count3_cyclic(
             _pad_lanes(ra, "r"), _pad_lanes(rb, "r"), _pad_lanes(sb, "s"),
             _pad_lanes(sc, "s"), _pad_lanes(tc, "t"), _pad_lanes(ta, "t"),
-            interpret=_interpret())
+            interpret=_scan_kernel_gate())
     return ref.bucket_count3_cyclic(ra, rb, sb, sc, tc, ta)
 
 
@@ -262,24 +284,29 @@ def _fused_cyclic_pairidx_ref(ra, rb, sb, sc, tc, ta):
     _, fp, _, cs = sb.shape
     _, _, _, ct = tc.shape
     tcs, tas = lex_sort_pairs(tc, ta)            # [hp, fp, uh, Ct]
-    b = hp * gp * uh * ug
-    ra_f = ra.reshape(b, cr)
-    rb_f = rb.reshape(b, cr)
+    b = gp * uh * ug
 
     def bcast(x, shape):
         return jnp.broadcast_to(x, shape).reshape((b,) + x.shape[-1:])
 
     def f_step(acc, ys):
         sb_f, sc_f, tcs_f, tas_f = ys            # [gp,ug,Cs], [hp,uh,Ct]
-        s_shape = (hp, gp, uh, ug, cs)
-        t_shape = (hp, gp, uh, ug, ct)
-        c = _pairidx_cell_counts(
-            ra_f, rb_f,
-            bcast(sb_f[None, :, None, :, :], s_shape),
-            bcast(sc_f[None, :, None, :, :], s_shape),
-            bcast(tcs_f[:, None, :, None, :], t_shape),
-            bcast(tas_f[:, None, :, None, :], t_shape))
-        return acc + c.reshape(hp, gp, uh, ug), None
+        s_shape = (gp, uh, ug, cs)
+        t_shape = (gp, uh, ug, ct)
+
+        def h_row(xs):
+            # one coarse H(A) row at a time: the per-bucket (Ct, Cr)
+            # prefix tables of the whole grid would not fit in memory
+            ra_i, rb_i, tcs_i, tas_i = xs        # [gp,uh,ug,Cr], [uh,Ct]
+            c = _pairidx_cell_counts(
+                ra_i.reshape(b, cr), rb_i.reshape(b, cr),
+                bcast(sb_f[:, None, :, :], s_shape),
+                bcast(sc_f[:, None, :, :], s_shape),
+                bcast(tcs_i[None, :, None, :], t_shape),
+                bcast(tas_i[None, :, None, :], t_shape))
+            return c.reshape(gp, uh, ug)
+
+        return acc + jax.lax.map(h_row, (ra, rb, tcs_f, tas_f)), None
 
     acc, _ = jax.lax.scan(
         f_step, jnp.zeros((hp, gp, uh, ug), jnp.int32),
@@ -335,6 +362,7 @@ def _fused_star_ref(rb, sb, sc, tc):
     return jnp.sum(wr * wt, axis=(0, 3)).astype(jnp.int32)
 
 
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
 def fused_count3_linear(rb, rv, sb, sc, sv, tc, tv, *,
                         use_kernel: bool = False):
     """Fused linear-3 sweep: per-(H, h) bucket counts [hp, u] int32."""
@@ -343,12 +371,15 @@ def fused_count3_linear(rb, rv, sb, sc, sv, tc, tv, *,
     sc = _mask(sc, sv, "s")
     tc = _mask(tc, tv, "t")
     if use_kernel:
+        u = rb.shape[1]
         return bucket_join.fused_count3_linear(
-            _pad_lanes(rb, "r"), _pad_lanes(sb, "s"), _pad_lanes(sc, "s"),
-            _pad_lanes(tc, "t"), interpret=_interpret())
+            _pad_tile(rb, "r", 1), _pad_tile(sb, "s", 2),
+            _pad_tile(sc, "s", 2), _pad_lanes(tc, "t"),
+            interpret=_interpret())[:, :u]
     return _fused_linear_ref(rb, sb, sc, tc)
 
 
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
 def fused_per_r_counts(rb, rv, sb, sc, sv, tc, tv, *,
                        use_kernel: bool = False):
     """Fused per-R-slot counts [hp, u, Cr] int32 (Example 1 aggregate)."""
@@ -358,13 +389,31 @@ def fused_per_r_counts(rb, rv, sb, sc, sv, tc, tv, *,
     sc = _mask(sc, sv, "s")
     tc = _mask(tc, tv, "t")
     if use_kernel:
+        u = rb.shape[1]
         out = bucket_join.fused_per_r_counts(
-            _pad_lanes(rb, "r"), _pad_lanes(sb, "s"), _pad_lanes(sc, "s"),
-            _pad_lanes(tc, "t"), interpret=_interpret())
-        return out[..., :cr]
+            _pad_tile(rb, "r", 1), _pad_tile(sb, "s", 2),
+            _pad_tile(sc, "s", 2), _pad_lanes(tc, "t"),
+            interpret=_interpret())
+        return out[:, :u, :cr]
     return _fused_per_r_ref(rb, sb, sc, tc)
 
 
+_CYCLIC_SWAP = "pallas-all-pairs (pair_index swapped out)"
+
+
+def cyclic_kernel(use_kernel: bool, pair_index: bool) -> str:
+    """Which sweep ``fused_count3_cyclic`` runs for these flags on this
+    backend.  The one documented swap: the pair-index kernel's binary-search
+    gathers do not lower to Mosaic, so compiled TPU runs the all-pairs MXU
+    kernel when ``pair_index=True`` asks for the pair index (and warns)."""
+    if not use_kernel:
+        return "jnp-pair-index" if pair_index else "jnp-all-pairs"
+    if not pair_index:
+        return "pallas-all-pairs"
+    return "pallas-pair-index" if _interpret() else _CYCLIC_SWAP
+
+
+@functools.partial(jax.jit, static_argnames=("use_kernel", "pair_index"))
 def fused_count3_cyclic(ra, rb, rv, sb, sc, sv, tc, ta, tv, *,
                         use_kernel: bool = False, pair_index: bool = True):
     """Fused cyclic sweep: per-cell counts [hp, gp, uh, ug] int32.
@@ -380,24 +429,28 @@ def fused_count3_cyclic(ra, rb, rv, sb, sc, sv, tc, ta, tv, *,
     sc = _mask(sc, sv, "s")
     tc = _mask(tc, tv, "t")
     ta = _mask(ta, tv, "t")
-    if use_kernel:
-        # The pair-index kernel's binary-search gathers don't lower to
-        # Mosaic yet: dispatch it only where Pallas runs in interpret mode
-        # (CPU validation); compiled TPU keeps the all-pairs MXU kernel.
-        if pair_index and _interpret():
-            tcs, tas = lex_sort_pairs(_pad_lanes(tc, "t"), _pad_lanes(ta, "t"))
-            return bucket_join.fused_count3_cyclic_pairidx(
-                _pad_lanes(ra, "r"), _pad_lanes(rb, "r"), _pad_lanes(sb, "s"),
-                _pad_lanes(sc, "s"), tcs, tas, interpret=True)
-        return bucket_join.fused_count3_cyclic(
+    kernel = cyclic_kernel(use_kernel, pair_index)
+    if kernel == _CYCLIC_SWAP:
+        warnings.warn("pair_index=True has no compiled Pallas kernel: the "
+                      "all-pairs MXU kernel runs instead", stacklevel=2)
+    if kernel == "pallas-pair-index":
+        tcs, tas = lex_sort_pairs(_pad_lanes(tc, "t"), _pad_lanes(ta, "t"))
+        return bucket_join.fused_count3_cyclic_pairidx(
             _pad_lanes(ra, "r"), _pad_lanes(rb, "r"), _pad_lanes(sb, "s"),
-            _pad_lanes(sc, "s"), _pad_lanes(tc, "t"), _pad_lanes(ta, "t"),
-            interpret=_interpret())
+            _pad_lanes(sc, "s"), tcs, tas, interpret=_interpret())
+    if use_kernel:
+        ug = ra.shape[3]
+        return bucket_join.fused_count3_cyclic(
+            _pad_tile(ra, "r", 3), _pad_tile(rb, "r", 3),
+            _pad_tile(sb, "s", 2), _pad_tile(sc, "s", 2),
+            _pad_lanes(tc, "t"), _pad_lanes(ta, "t"),
+            interpret=_interpret())[..., :ug]
     if pair_index:
         return _fused_cyclic_pairidx_ref(ra, rb, sb, sc, tc, ta)
     return _fused_cyclic_ref(ra, rb, sb, sc, tc, ta)
 
 
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
 def fused_count3_star(rb, rv, sb, sc, sv, tc, tv, *,
                       use_kernel: bool = False):
     """Fused star sweep: per-PMU counts [uh, ug] int32."""
@@ -406,9 +459,11 @@ def fused_count3_star(rb, rv, sb, sc, sv, tc, tv, *,
     sc = _mask(sc, sv, "s")
     tc = _mask(tc, tv, "t")
     if use_kernel:
+        ug = tc.shape[0]
         return bucket_join.fused_count3_star(
-            _pad_lanes(rb, "r"), _pad_lanes(sb, "s"), _pad_lanes(sc, "s"),
-            _pad_lanes(tc, "t"), interpret=_interpret())
+            _pad_lanes(rb, "r"), _pad_tile(sb, "s", 2),
+            _pad_tile(sc, "s", 2), _pad_tile(tc, "t", 0),
+            interpret=_interpret())[:, :ug]
     return _fused_star_ref(rb, sb, sc, tc)
 
 

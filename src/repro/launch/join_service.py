@@ -281,6 +281,9 @@ def main(argv=None):
 
     import numpy as np
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     rng = np.random.default_rng(args.seed)
     n, d = args.rows, args.distinct
 

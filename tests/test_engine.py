@@ -1,6 +1,7 @@
 """MultiwayJoinEngine: fused sweeps vs scan drivers vs kernels/ref.py,
 plus the skew-recovery guarantee (exact counts, no residual overflow)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from hypothesis import given, settings, strategies as st
@@ -250,8 +251,11 @@ def test_planner_cyclic_always_3way(rng):
 # --------------------------------------------------------------------------
 
 def _probe_hashing(monkeypatch):
-    """Count composite_ids invocations and raw hash_bucket evaluations."""
+    """Count composite_ids invocations and raw hash_bucket evaluations.
+    The hashing runs inside jitted programs, so the jit caches are cleared
+    first: every pass is then traced, and counted, once."""
     from repro.core import hashing, partition
+    jax.clear_caches()
     calls = {"composite": 0, "hash": 0}
     orig_ci = partition.composite_ids
     orig_hb = hashing.hash_bucket

@@ -119,6 +119,38 @@ def test_bucketize_overflow_detection(rng):
     assert int(np.asarray(b.counts).max()) == 100
 
 
+def test_overflowed_bucket_keeps_the_first_rows_in_arrival_order(rng):
+    rel = Relation.from_arrays(k=np.zeros(40, np.int32),
+                               v=np.arange(40, dtype=np.int32))
+    b = partition.bucketize(rel, "k", 4, capacity=16, fn="h")
+    assert bool(b.overflowed)
+    row = int(np.asarray(hashing.hash_bucket(jnp.zeros(1, jnp.int32), 4,
+                                             "h"))[0])
+    np.testing.assert_array_equal(np.asarray(b.columns["v"])[row],
+                                  np.arange(16))
+    assert np.asarray(b.valid).sum() == 16
+
+
+def test_layouts_of_one_capacity_share_one_sort_program(rng):
+    """A TPU sort takes 15-20 s to compile, so the sort behind every layout
+    and binary-step build is one program keyed only by the key count:
+    layouts that differ in buckets, capacity or columns reuse it."""
+    import jax
+
+    from repro.core import binary_join
+
+    jax.clear_caches()
+    a, _ = make_rel(rng, 200, ("k", "v"), 30)
+    b, _ = make_rel(rng, 200, ("k", "w", "x"), 30)
+    assert a.capacity == b.capacity
+    partition.bucketize(a, "k", 8, 64, fn="h")
+    partition.bucketize(b, "w", 16, 32, fn="g", salt=3)
+    ids, nb = partition.composite_ids(a, [("k", 4, "H"), ("v", 4, "h")])
+    partition.bucketize_by_ids(a, ids, nb, 32, (4, 4))
+    binary_join.stage_join(b, a, build_key="k", probe_key="k")
+    assert partition.stable_order._cache_size() == 1
+
+
 def test_composite_ids_lexicographic(rng):
     rel, data = make_rel(rng, 64, ("x", "y"), 20)
     ids, total = partition.composite_ids(

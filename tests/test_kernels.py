@@ -1,6 +1,8 @@
 """Pallas kernels vs pure-jnp oracles — interpret=True sweeps over
 shapes/dtypes.  Counts are integers, so checks are exact equality."""
 
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -149,3 +151,99 @@ def test_fm_registers_ref_matches_direct_sketch(rng):
         want = sketches.add(sketches.empty(K), key,
                             jnp.ones(key.shape, bool))
         np.testing.assert_array_equal(np.asarray(got[bi]), np.asarray(want))
+
+
+# --------------------------------------------------------------------------
+# fused kernels: 8-row tiles, lane-dense count blocks, chunked streaming
+# --------------------------------------------------------------------------
+
+def _grid(rng, shape, d):
+    keys = rng.integers(0, d, size=shape).astype(np.int32)
+    return jnp.asarray(keys), jnp.asarray(rng.random(shape) < 0.85)
+
+
+# PMU axes off the 8-row tile (padded with sentinel rows) and capacities
+# past one 512-lane chunk (multi-step in-kernel loops, several S chunks)
+FUSED_CASES = {
+    "linear": dict(hp=2, u=5, gp=3, cr=600, cs=700, ct=1100),
+    "per_r": dict(hp=1, u=11, gp=2, cr=530, cs=300, ct=260),
+    "cyclic": dict(hp=2, gp=1, uh=3, ug=10, fp=2, cr=300, cs=520, ct=130),
+    "star": dict(ch=2, uh=3, ug=9, cr=200, cs=600, ct=300),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(FUSED_CASES))
+def test_fused_kernel_tiling_matches_jnp(kind):
+    """The Mosaic-tiled fused kernels (interpret mode) and the fused jnp
+    paths are the same function at shapes that exercise row padding,
+    chunk loops and the streamed S-chunk grid axis."""
+    g = FUSED_CASES[kind]
+    rng = np.random.default_rng(len(kind))
+    d = 40
+    if kind in ("linear", "per_r"):
+        rb, rv = _grid(rng, (g["hp"], g["u"], g["cr"]), d)
+        sb, sv = _grid(rng, (g["hp"], g["gp"], g["u"], g["cs"]), d)
+        sc, _ = _grid(rng, sb.shape, d)
+        tc, tv = _grid(rng, (g["gp"], g["ct"]), d)
+        fn = (ops.fused_count3_linear if kind == "linear"
+              else ops.fused_per_r_counts)
+        args = (rb, rv, sb, sc, sv, tc, tv)
+    elif kind == "cyclic":
+        r_shape = (g["hp"], g["gp"], g["uh"], g["ug"], g["cr"])
+        ra, rv = _grid(rng, r_shape, d)
+        rb, _ = _grid(rng, r_shape, d)
+        sb, sv = _grid(rng, (g["gp"], g["fp"], g["ug"], g["cs"]), d)
+        sc, _ = _grid(rng, sb.shape, d)
+        tc, tv = _grid(rng, (g["hp"], g["fp"], g["uh"], g["ct"]), d)
+        ta, _ = _grid(rng, tc.shape, d)
+        fn = functools.partial(ops.fused_count3_cyclic, pair_index=False)
+        args = (ra, rb, rv, sb, sc, sv, tc, ta, tv)
+    else:
+        rb, rv = _grid(rng, (g["uh"], g["cr"]), d)
+        sb, sv = _grid(rng, (g["ch"], g["uh"], g["ug"], g["cs"]), d)
+        sc, _ = _grid(rng, sb.shape, d)
+        tc, tv = _grid(rng, (g["ug"], g["ct"]), d)
+        fn = ops.fused_count3_star
+        args = (rb, rv, sb, sc, sv, tc, tv)
+    want = np.asarray(fn(*args, use_kernel=False))
+    got = np.asarray(fn(*args, use_kernel=True))
+    assert got.shape == want.shape
+    assert want.sum() > 0
+    np.testing.assert_array_equal(got, want)
+
+
+def test_scan_driver_kernels_refuse_compiled_dispatch(monkeypatch):
+    """The per-bucket scan-driver kernels never reach Mosaic: with a TPU
+    backend they raise instead of compiling an unaligned block spec."""
+    rng = np.random.default_rng(0)
+    ka, va = _mk(rng, 2, 128, 9, "a")
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    with pytest.raises(NotImplementedError, match="interpret-only"):
+        ops.bucket_pair_count(ka, va, ka, va, use_kernel=True)
+
+
+def test_cyclic_kernel_names_the_compiled_swap(monkeypatch):
+    assert ops.cyclic_kernel(False, True) == "jnp-pair-index"
+    assert ops.cyclic_kernel(True, True) == "pallas-pair-index"
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    assert ops.cyclic_kernel(True, False) == "pallas-all-pairs"
+    assert "swapped" in ops.cyclic_kernel(True, True)
+
+
+def _cyclic_pairidx_temp_bytes(hp):
+    import jax
+    gp, uh, ug, fp, cr, cs, ct = 8, 8, 8, 8, 8, 56, 64
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    args = ([i32((hp, gp, uh, ug, cr))] * 2 + [i32((gp, fp, ug, cs))] * 2
+            + [i32((hp, fp, uh, ct))] * 2)
+    compiled = jax.jit(ops._fused_cyclic_pairidx_ref).lower(*args).compile()
+    return compiled.memory_analysis().temp_size_in_bytes
+
+
+def test_cyclic_pairidx_ref_working_set_is_one_h_row():
+    """The jnp pair-index triangle sweep builds its per-bucket (Ct, Cr)
+    prefix tables one coarse H row at a time, so its working set does not
+    grow with the H grid.  Built for the whole grid at once, the triangle
+    query's plan over 200k edges needs ~13.8 GB of scratch on a 16 GB v5e."""
+    one, eight = _cyclic_pairidx_temp_bytes(1), _cyclic_pairidx_temp_bytes(8)
+    assert eight < 2 * one

@@ -363,3 +363,21 @@ def test_watch_requires_session():
     before = len(sq.delta_rounds)
     R.append(a=np.arange(5, dtype=np.int32), b=np.arange(5, dtype=np.int32))
     assert len(sq.delta_rounds) == before
+
+
+@pytest.mark.parametrize("small", ["f1", "f3"])
+def test_delta_cascade_materializes_the_delta_side(rng, small):
+    """A delta in either endpoint of a linear standing query materializes
+    Δ ⋈ f2, never the full f1 ⋈ f2 (which at 16M edges per relation is
+    256M rows and exhausts a 16 GB device)."""
+    from repro.core import planner
+    rels = {nm: make_rel(rng, 400, ("src", "dst"), 50)[0]
+            for nm in ("f1", "f2", "f3")}
+    q = Query(rels, [("f1.dst", "f2.src"), ("f2.dst", "f3.src")])
+    cards = {"f1": 400, "f2": 400, "f3": 400, small: 5}
+    qp = planner.plan_query(q, cards, m_budget=64, strategy="cascade")
+    first = qp.steps[0]
+    assert first.op == "binary" and not first.aggregate
+    assert set(first.inputs) == {small, "f2"}
+    assert int(JoinSession(m_budget=64).execute(q, strategy="cascade").count) \
+        == int(JoinSession(m_budget=64).execute(q, strategy="3way").count)
