@@ -31,7 +31,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.core import cyclic3, engine, linear3, plan_ir, star3  # noqa: E402
+from repro.core import cyclic3, engine, linear3, star3  # noqa: E402
 from repro.core.query import Query  # noqa: E402
 from repro.core.relation import Relation  # noqa: E402
 from repro.core.session import JoinSession  # noqa: E402
@@ -39,7 +39,6 @@ from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.perfmodel import Calibration, calibrate  # noqa: E402
 
 OUT = pathlib.Path("BENCH_engine.json")
-STEPS_OUT = pathlib.Path("BENCH_plan_steps.json")
 CAL_OUT = pathlib.Path(calibrate.CALIBRATION_FILE)
 
 
@@ -286,10 +285,9 @@ def _tree6_oracle(q) -> int:
 
 def bench_plan_pipeline_6way(rng, n, d, m_budget, repeats):
     """The overlapped DAG executor on a 6-relation tree with two
-    independent branches: the default (overlapped) walk is timed, then one
-    ``profile=True`` walk blocks per step to attribute time
-    (``StepStats.wall_s`` / ``dispatch_s`` — the per-step record CI
-    uploads).  Gated on exact agreement with a numpy backflow oracle."""
+    independent branches: the overlapped walk is timed (per-step device
+    time comes from a profiler trace's ``repro.plan.step`` spans).  Gated
+    on exact agreement with a numpy backflow oracle."""
     q = _tree6_query(rng, n, d)
     sess = JoinSession(m_budget=m_budget)
     cold = sess.execute(q)                      # decompose + compile
@@ -297,22 +295,14 @@ def bench_plan_pipeline_6way(rng, n, d, m_budget, repeats):
     for _ in range(max(repeats, 2)):
         w = sess.execute(q)
         exec_ms = min(exec_ms, w.exec_s * 1e3)
-    prof = plan_ir.execute_plan(cold.plan, dict(q.relations), profile=True)
-    step_timings = [
-        {"op": s.op, "out": s.out, "rows": int(s.rows),
-         "exec_ms": s.exec_s * 1e3, "dispatch_ms": s.dispatch_s * 1e3,
-         "wall_ms": s.wall_s * 1e3}
-        for s in prof.step_stats]
-    profile_ms = sum(s["wall_ms"] for s in step_timings)
     oracle = _tree6_oracle(q)
     return {"n": n, "d": d, "n_relations": 6,
             "steps": len(cold.plan.steps),
             "fused3_steps": len(cold.plan.fused3_steps),
             "strategy": cold.strategy,
-            "exec_ms": exec_ms, "profile_ms": profile_ms,
-            "step_timings": step_timings,
+            "exec_ms": exec_ms,
             "count": int(cold.count), "oracle_count": oracle,
-            "match": (int(cold.count) == oracle == int(prof.count)
+            "match": (int(cold.count) == oracle == int(w.count)
                       and not cold.overflowed
                       and len(cold.plan.steps) >= 4)}
 
@@ -457,8 +447,7 @@ def main():
                   f"match={row['match']}")
         elif "exec_ms" in row:
             print(f"  {name}: exec {row['exec_ms']:.1f} ms overlapped "
-                  f"({row['steps']} steps), profiled "
-                  f"{row['profile_ms']:.1f} ms, match={row['match']}")
+                  f"({row['steps']} steps), match={row['match']}")
         else:
             print(f"  {name}: cold plan {row['cold_plan_ms']:.2f} ms, "
                   f"warm plan {row['warm_plan_ms']:.3f} ms, "
@@ -540,11 +529,6 @@ def main():
     cal = calibrate.refresh_calibration_file(report, CAL_OUT)
     print(f"  calibration -> {CAL_OUT} (fused3 {cal.fused3_scale:.3g}, "
           f"cascade {cal.cascade_scale:.3g}, {cal.source})")
-    # per-step timing record (CI uploads this next to BENCH_engine.json)
-    STEPS_OUT.write_text(json.dumps({
-        "backend": jax.default_backend(), "quick": bool(args.quick),
-        "plan_pipeline_6way": shapes["plan_pipeline_6way"]["step_timings"],
-    }, indent=2))
     cache_ok = bool(cache["warm_cache_hits"])
     nway_ok = bool(report["claim_nway_plan_ir"]["ok"])
     cal_ok = bool(report["claim_calibrated_plan_never_loses"]["ok"])

@@ -47,6 +47,7 @@ from repro.core.reference import host_join_count  # noqa: F401  (oracle —
 #   lives in core.reference now, the one np.unique-allowed module; kept
 #   re-exported here because it is THE parity oracle for this module)
 from repro.core.relation import SENTINEL, Relation
+from repro.core.spans import to_host
 
 _MASK15 = 0x7FFF
 
@@ -110,8 +111,8 @@ def exact_join_count(build: Relation, build_key: str,
     size materialized intermediates exactly (a materialize step cannot
     overflow) and as the root aggregate of an all-binary cascade —
     ``host_join_count`` is the np.unique oracle it is tested against."""
-    hi, lo = _device_count_jit(build, probe, build_key=build_key,
-                               probe_key=probe_key)
+    hi, lo = to_host("total", _device_count_jit(
+        build, probe, build_key=build_key, probe_key=probe_key))
     return (int(hi) << 30) + int(lo)
 
 
@@ -232,9 +233,12 @@ def stage_join(build: Relation, probe: Relation, *, build_key: str,
 
 
 def staged_total(staged: StagedJoin) -> int:
-    """Host-sync the exact join cardinality of a staged step (two int32
-    scalars — the pipeline's only host↔device traffic)."""
-    return (int(staged.total_hi) << 30) + int(staged.total_lo)
+    """Host-sync the exact join cardinality of a staged step: two int32
+    scalars, read by the executor to size the gather.  The executor also
+    reads each step's input cardinalities, and the session the
+    cardinalities of the base relations."""
+    hi, lo = to_host("total", (staged.total_hi, staged.total_lo))
+    return (int(hi) << 30) + int(lo)
 
 
 def bucket_capacity(total: int) -> int:

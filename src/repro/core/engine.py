@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from repro.core import cyclic3, linear3, partition, recovery, star3
 from repro.core.recovery import EngineResult, PerRResult  # noqa: F401  (re-export)
-from repro.core.relation import Relation
+from repro.core.relation import Relation, live_rows
 from repro.kernels import ops as kops
 
 
@@ -276,8 +276,7 @@ class MultiwayJoinEngine:
         if plan is None:
             if m_budget is None:
                 raise ValueError("pass a plan or m_budget")
-            plan = self.default_plan(int(r.n), int(s.n), int(t.n),
-                                     m_budget=m_budget)
+            plan = self.default_plan(*live_rows(r, s, t), m_budget=m_budget)
         if binding is not None:
             if binding.kind != self.kind:
                 raise ValueError(f"binding classified {binding.kind!r}, "

@@ -19,17 +19,18 @@ triangle query):
     the root step writes :data:`COUNT`.
   * :func:`execute_plan` — the ONE executor, device-resident end to end.
     Each binary materialize step runs as a compiled two-dispatch pipeline
-    (``binary_join.stage_join`` → ``gather_staged``) whose only host↔
-    device traffic is the two-scalar exact total that sizes the output
-    buffer (log-bucketed static capacities, so refreshed executions hit
-    the same compiled gather).  Steps overlap: before the executor blocks
-    on a step's total it dispatches stage 1 of every later binary step
-    whose inputs are already live (independent DAG branches run
-    concurrently under JAX async dispatch), and a refcounting buffer
-    arena drops each ``%i<k>`` intermediate the moment its last consumer
-    has captured it.  ``base_salt``/``max_rounds``/``growth`` thread
+    (``binary_join.stage_join`` → ``gather_staged``) whose host reads are
+    the two-scalar exact total that sizes the output buffer (log-bucketed
+    static capacities, so refreshed executions hit the same compiled
+    gather) and the step's two input cardinalities.  Steps overlap:
+    before the executor blocks on a step's total it dispatches stage 1
+    of every later binary step whose inputs are already live
+    (independent DAG branches run concurrently under JAX async dispatch),
+    and a refcounting buffer arena drops each ``%i<k>`` intermediate the
+    moment its last consumer has captured it.  ``base_salt``/``max_rounds``/``growth`` thread
     through every fused step; count / tuples_read / recovery rounds /
-    per-step timings aggregate into a single result.
+    per-step timings aggregate into a single result.  Each step runs
+    under a ``repro.plan.step`` span (``core.spans``).
 
 ``planner.plan_query`` is the decomposer that produces these plans;
 ``session.JoinSession.execute`` walks them.  The legacy
@@ -44,7 +45,6 @@ import os
 import time
 from typing import Mapping, NamedTuple
 
-import jax
 import numpy as np
 
 from repro.analysis import arena_sanitizer
@@ -52,7 +52,8 @@ from repro.analysis.errors import (PlanPerRError, PlanStructureError,
                                    PlanWidthError)
 from repro.core import binary_join, engine, recovery
 from repro.core.query import Predicate
-from repro.core.relation import Relation
+from repro.core.relation import Relation, live_rows
+from repro.core.spans import span, to_host
 
 # The root step's output name: the aggregated COUNT of the whole query.
 COUNT = "%count"
@@ -145,13 +146,10 @@ class StepStats(NamedTuple):
     """Per-step execution record (aggregated onto the QueryResult).
 
     ``exec_s`` is the host time the executor's loop spent on the step —
-    under async dispatch that is mostly the blocking two-scalar total
-    sync, NOT the device work.  ``dispatch_s`` is the slice of it spent
-    enqueueing the step's compiled calls (stage + gather).  ``wall_s`` is
-    the step's start-to-buffers-ready wall time and is only populated
-    when ``execute_plan(..., profile=True)`` blocks per step — it is 0.0
-    on the overlapped default path, where per-step wall time is not a
-    well-defined quantity."""
+    under async dispatch that is mostly the blocking host reads, NOT the
+    device work.  Device time per step comes from a profiler trace, where
+    each step is a ``repro.plan.step`` span on the same clock as the
+    device ops."""
 
     op: str
     out: str
@@ -159,8 +157,6 @@ class StepStats(NamedTuple):
     rounds: int              # recovery rounds (0 for binary steps)
     tuples_read: int
     exec_s: float
-    dispatch_s: float = 0.0  # host time enqueueing compiled calls
-    wall_s: float = 0.0      # blocked wall time (profile=True only)
 
 
 class PlanExecResult(NamedTuple):
@@ -201,7 +197,6 @@ class _Staged(NamedTuple):
     probe: Relation            # projected probe side (stage 2 reads it)
     na: object                 # device scalars: live input cardinalities
     nb: object                 # (synced with the total, not eagerly)
-    dispatch_s: float
 
 
 def _stage_binary(step: PlanStep, env) -> _Staged:
@@ -210,9 +205,8 @@ def _stage_binary(step: PlanStep, env) -> _Staged:
     proj_a, proj_b = step.project if step.project else ((), ())
     a2, b2 = _project(a, proj_a), _project(b, proj_b)
     ka, kb = _step_keys(step)
-    t0 = time.perf_counter()
     st = binary_join.stage_join(a2, b2, build_key=ka, probe_key=kb)
-    return _Staged(st, b2, a.n, b.n, time.perf_counter() - t0)
+    return _Staged(st, b2, a.n, b.n)
 
 
 def _run_fused3(step: PlanStep, plan: QueryPlan, env):
@@ -229,8 +223,7 @@ def _run_fused3(step: PlanStep, plan: QueryPlan, env):
         growth=plan.growth, base_salt=plan.base_salt)
     shape = step.shape_plan
     if shape is None:
-        shape = eng.default_plan(int(r.n), int(s.n), int(t.n),
-                                 m_budget=plan.m_budget)
+        shape = eng.default_plan(*live_rows(r, s, t), m_budget=plan.m_budget)
     if step.per_r_key is not None:
         if step.kind != "linear":
             raise PlanPerRError(
@@ -245,7 +238,6 @@ def _run_fused3(step: PlanStep, plan: QueryPlan, env):
 
 
 def execute_plan(plan: QueryPlan, relations: Mapping[str, Relation], *,
-                 profile: bool = False,
                  keep_intermediates: bool = False) -> PlanExecResult:
     """Walk the DAG: materialize intermediates, aggregate at the root.
 
@@ -267,10 +259,6 @@ def execute_plan(plan: QueryPlan, relations: Mapping[str, Relation], *,
     sums, and fused steps inherit the recovery engine's exact-histogram
     final round.
 
-    ``profile=True`` blocks on each step's output buffers and fills
-    ``StepStats.wall_s`` — attribution mode for benches; it serializes
-    the overlap, so leave it off on the hot path.
-
     ``keep_intermediates=True`` disables the arena drop and returns every
     materialized ``%i<k>`` on ``PlanExecResult.intermediates`` — the
     standing-query path, which keeps them resident and refreshes them
@@ -282,8 +270,8 @@ def execute_plan(plan: QueryPlan, relations: Mapping[str, Relation], *,
         from repro.analysis import verify_plan as _verify
         from repro.analysis import widths as _widths
         _verify.verify_plan(plan, external=set(relations))
-        _widths.check_widths(
-            plan, {name: int(rel.n) for name, rel in relations.items()})
+        _widths.check_widths(plan, dict(zip(
+            relations, live_rows(*relations.values()))))
 
     steps = plan.steps
     env: dict[str, Relation] = dict(relations)
@@ -326,62 +314,54 @@ def execute_plan(plan: QueryPlan, relations: Mapping[str, Relation], *,
     stats: list[StepStats] = []
     for i, step in enumerate(steps):
         t0 = time.perf_counter()
-        if step.op == "binary":
-            stage_ready(i)
-            sg = staged.pop(i)
-            dispatch_s = sg.dispatch_s
-            total = binary_join.staged_total(sg.staged)  # sync: 2 scalars
-            tuples = int(sg.na) + int(sg.nb)
-            if step.aggregate:
-                count = total
-                out = None
+        with span("plan.step", i=i, op=step.op, out=step.out):
+            if step.op == "binary":
+                stage_ready(i)
+                sg = staged.pop(i)
+                total = binary_join.staged_total(sg.staged)
+                tuples = int(to_host("rows", (sg.na, sg.nb)).sum())
+                if step.aggregate:
+                    count = total
+                else:
+                    if total >= 2**31:
+                        raise PlanWidthError(
+                            f"intermediate {step.out} has {total} rows — "
+                            "too large to materialize; re-plan with "
+                            "strategy='3way' (the fused 3-way engine never "
+                            "materializes the join output)", step=step)
+                    cap = binary_join.bucket_capacity(total)
+                    out = binary_join.gather_staged(sg.staged, sg.probe, cap)
+                    if shadow is not None:
+                        shadow.on_produce(step.out)
+                    env[step.out] = out
+                    tuples += total           # intermediate written once
+                    # producing %i<k> may unblock dependent steps: overlap
+                    # their stage 1 with this gather already in flight
+                    stage_ready(i + 1)
+                rows = count if step.aggregate else total
+                total_tuples += tuples
+                stats.append(StepStats("binary", step.out, rows, 0, tuples,
+                                       time.perf_counter() - t0))
+            elif step.op == "fused3":
+                if not step.aggregate:
+                    raise PlanStructureError(
+                        "fused3 steps aggregate (the engine never "
+                        f"materializes its output); step {step.out!r} "
+                        "tries to materialize", step=step)
+                res = _run_fused3(step, plan, env)
+                for n in step.inputs:
+                    release(n)
+                if step.per_r_key is not None:
+                    per_r = res
+                count = int(res.count)
+                total_tuples += int(res.tuples_read)
+                rounds += int(res.rounds)
+                stats.append(StepStats(
+                    "fused3", step.out, count, int(res.rounds),
+                    int(res.tuples_read), time.perf_counter() - t0))
             else:
-                if total >= 2**31:
-                    raise PlanWidthError(
-                        f"intermediate {step.out} has {total} rows — too "
-                        "large to materialize; re-plan with "
-                        "strategy='3way' (the fused 3-way engine never "
-                        "materializes the join output)", step=step)
-                cap = binary_join.bucket_capacity(total)
-                t_d = time.perf_counter()
-                out = binary_join.gather_staged(sg.staged, sg.probe, cap)
-                dispatch_s += time.perf_counter() - t_d
-                if shadow is not None:
-                    shadow.on_produce(step.out)
-                env[step.out] = out
-                tuples += total               # intermediate written once
-                # producing %i<k> may unblock dependent steps: overlap
-                # their stage 1 with this gather already in flight
-                stage_ready(i + 1)
-            if profile and out is not None:
-                jax.block_until_ready(out)
-            rows = count if step.aggregate else total
-            total_tuples += tuples
-            stats.append(StepStats(
-                "binary", step.out, rows, 0, tuples,
-                time.perf_counter() - t0, dispatch_s,
-                (time.perf_counter() - t0) if profile else 0.0))
-        elif step.op == "fused3":
-            if not step.aggregate:
                 raise PlanStructureError(
-                    "fused3 steps aggregate (the engine never materializes "
-                    f"its output); step {step.out!r} tries to materialize",
-                    step=step)
-            res = _run_fused3(step, plan, env)
-            for n in step.inputs:
-                release(n)
-            if step.per_r_key is not None:
-                per_r = res
-            count = int(res.count)
-            total_tuples += int(res.tuples_read)
-            rounds += int(res.rounds)
-            stats.append(StepStats(
-                "fused3", step.out, count, int(res.rounds),
-                int(res.tuples_read), time.perf_counter() - t0, 0.0,
-                (time.perf_counter() - t0) if profile else 0.0))
-        else:
-            raise PlanStructureError(f"unknown plan-step op {step.op!r}",
-                                     step=step)
+                    f"unknown plan-step op {step.op!r}", step=step)
     overflowed = bool(per_r.overflowed) if per_r is not None else False
     if shadow is not None:
         shadow.finish(env)

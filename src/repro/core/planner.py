@@ -445,7 +445,7 @@ def plan_query(query: Query, cards=None, *, m_budget: int | None = None,
         # the fused root IS the per-R implementation — pin it
         strategy = "3way"
     if cards is None:
-        cards = {nm: int(rel.n) for nm, rel in rels.items()}
+        cards = query.live_cards()
     edges = query.edges()
 
     # connectivity over ALL N relations (classify only checks 3)

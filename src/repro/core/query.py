@@ -35,7 +35,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterable, Mapping
 
-from repro.core.relation import Relation
+from repro.core.relation import Relation, live_rows
 
 # A path-shaped (hub) query is classified as the paper's star schema when
 # the centre relation is at least this many times larger than EACH endpoint
@@ -136,9 +136,6 @@ class Binding:
     def relations(self) -> tuple[Relation, Relation, Relation]:
         return self.rels["r"], self.rels["s"], self.rels["t"]
 
-    def cardinalities(self) -> tuple[int, int, int]:
-        return tuple(int(self.rels[k].n) for k in ("r", "s", "t"))
-
     def kind_ops(self, **kw):
         """The recovery KindOps for this query, built FROM the binding."""
         from repro.core import recovery
@@ -212,6 +209,10 @@ class Query:
                      for name, rel in self.relations.items())
         preds = tuple((p.left, p.right) for p in self.predicates)
         return rels, preds
+
+    def live_cards(self) -> dict[str, int]:
+        """Live rows of each relation, read to the host in one sync."""
+        return dict(zip(self.relations, live_rows(*self.relations.values())))
 
     def edges(self) -> dict[frozenset, Predicate]:
         """The predicate graph's edge set: ``frozenset({rel_a, rel_b}) ->
@@ -299,8 +300,7 @@ class Query:
         e_rs = edges[frozenset((r, centre))]
         e_st = edges[frozenset((centre, t))]
         if cardinalities is None:
-            cardinalities = {n: int(rel.n)
-                             for n, rel in self.relations.items()}
+            cardinalities = self.live_cards()
         n_c = cardinalities[centre]
         hub = n_c >= star_fact_ratio * max(cardinalities[r],
                                            cardinalities[t], 1)

@@ -61,8 +61,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import partition
-from repro.core.relation import Relation
+from repro.core.relation import Relation, live_rows
 from repro.core.results import JoinResult, PerRResult  # noqa: F401 (re-export)
+from repro.core.spans import span, to_host
 from repro.kernels import ops as kops
 
 # Internal alias (see core.results): the recovery loop's scalar result IS
@@ -98,7 +99,7 @@ def hash_pass(rel: Relation, specs, out_shape: tuple, salt: int) -> RelPass:
     """THE hashing pass: composite ids + the exact histogram derived from
     them.  Everything else a round needs re-uses the returned ids."""
     ids, nb = partition.composite_ids(rel, specs, salt)
-    hist = np.bincount(np.asarray(ids), minlength=nb + 1)[:nb]
+    hist = np.bincount(to_host("hist", ids), minlength=nb + 1)[:nb]
     return RelPass(ids, nb, hist.reshape(out_shape), out_shape)
 
 
@@ -110,7 +111,7 @@ def layout(rel: Relation, p: RelPass, cap: int) -> partition.Buckets:
 def cell_of(p: RelPass, inner: int, n_cells: int) -> np.ndarray:
     """Coarse-cell id per row from composite-id arithmetic (no re-hash).
     Invalid rows land on a clipped cell; callers AND with ``rel.valid``."""
-    return np.clip(np.asarray(p.ids) // inner, 0, n_cells - 1)
+    return np.clip(to_host("cells", p.ids) // inner, 0, n_cells - 1)
 
 
 # ==========================================================================
@@ -167,8 +168,8 @@ class LinearOps:
                 "s": rels["s"].mask_where(jnp.asarray(bad[s_cell]))}
 
     def tuples_read(self, rels, plan):
-        return (int(rels["r"].n) + int(rels["s"].n)
-                + plan.h_parts * int(rels["t"].n))
+        n_r, n_s, n_t = live_rows(rels["r"], rels["s"], rels["t"])
+        return n_r + n_s + plan.h_parts * n_t
 
 
 class CyclicOps:
@@ -229,8 +230,8 @@ class CyclicOps:
                     jnp.asarray(bad.reshape(-1)[r_cell]))}
 
     def tuples_read(self, rels, plan):
-        return (int(rels["r"].n) + plan.h_parts * int(rels["s"].n)
-                + plan.g_parts * int(rels["t"].n))
+        n_r, n_s, n_t = live_rows(rels["r"], rels["s"], rels["t"])
+        return n_r + plan.h_parts * n_s + plan.g_parts * n_t
 
 
 class StarOps:
@@ -264,7 +265,7 @@ class StarOps:
         ids = jnp.where(rel.valid,
                         chunk * nb2 + jnp.clip(ids2, 0, nb2 - 1),
                         jnp.int32(nb))
-        hist = np.bincount(np.asarray(ids), minlength=nb + 1)[:nb]
+        hist = np.bincount(to_host("hist", ids), minlength=nb + 1)[:nb]
         return RelPass(ids, nb, hist.reshape(ch, uh, ug), (ch, uh, ug))
 
     def size_caps(self, plan, passes, final):
@@ -291,14 +292,14 @@ class StarOps:
 
     def residual(self, rels, passes, bad, plan):
         uh, ug = plan.uh, plan.ug
-        s_cell = np.asarray(passes["s"].ids) % (uh * ug)
+        s_cell = to_host("cells", passes["s"].ids) % (uh * ug)
         s_cell = np.clip(s_cell, 0, uh * ug - 1)
         return {**rels,
                 "s": rels["s"].mask_where(
                     jnp.asarray(bad.reshape(-1)[s_cell]))}
 
     def tuples_read(self, rels, plan):
-        return int(rels["r"].n) + int(rels["s"].n) + int(rels["t"].n)
+        return sum(live_rows(rels["r"], rels["s"], rels["t"]))
 
 
 OPS = {"linear": LinearOps, "cyclic": CyclicOps, "star": StarOps}
@@ -333,23 +334,27 @@ def run_count_rounds(ops, r: Relation, s: Relation, t: Relation, plan, *,
                      use_kernel: bool = False,
                      base_salt: int = 0) -> EngineResult:
     """The shared recovery loop: fused sweep, keep exact partials, re-run
-    overflowed cells, exact-sized final round (see module docstring)."""
+    overflowed cells, exact-sized final round (see module docstring).
+    Each round runs under a ``repro.recovery.round`` span and each
+    residual mask under ``repro.recovery.residual``."""
     rels = {"r": r, "s": s, "t": t}
     total, tuples = 0, 0
     for rnd in range(max_rounds + 1):
         final = rnd == max_rounds
-        plan, passes, layouts = _round_pass(ops, rels, plan,
-                                            base_salt + rnd, final)
-        counts = np.asarray(ops.count(layouts, plan, use_kernel),
-                            dtype=np.int64)
-        bad = ops.bad_cells(passes, plan)
-        tuples += ops.tuples_read(rels, plan)
+        with span("recovery.round", round=rnd, final=int(final)):
+            plan, passes, layouts = _round_pass(ops, rels, plan,
+                                                base_salt + rnd, final)
+            counts = to_host("counts", ops.count(layouts, plan, use_kernel)
+                             ).astype(np.int64)
+            bad = ops.bad_cells(passes, plan)
+            tuples += ops.tuples_read(rels, plan)
         if final or not bad.any():
             total += int(counts.sum())
             return EngineResult(np.int64(total), jnp.asarray(False),
                                 np.int64(tuples), rnd + 1)
         total += int((counts * ops.good_weight(bad)).sum())
-        rels = ops.residual(rels, passes, bad, plan)
+        with span("recovery.residual", round=rnd):
+            rels = ops.residual(rels, passes, bad, plan)
         plan = grown(plan, growth)
     raise AssertionError("unreachable: final round is exact-sized")
 
@@ -366,32 +371,35 @@ def run_per_r_rounds(ops: LinearOps, r: Relation, s: Relation, t: Relation,
     rounds, tuples = 0, 0
     for rnd in range(max_rounds + 1):
         final = rnd == max_rounds
-        plan, passes, layouts = _round_pass(ops, rels, plan,
-                                            base_salt + rnd, final)
-        tuples += ops.tuples_read(rels, plan)
-        rg = layouts["r"]
-        counts = kops.fused_per_r_counts(
-            rg.columns[ops.rb], rg.valid, layouts["s"].columns[ops.sb],
-            layouts["s"].columns[ops.sc], layouts["s"].valid,
-            layouts["t"].columns[ops.tc], layouts["t"].valid,
-            use_kernel=use_kernel)                            # [hp, u, Cr]
-        bad = ops.bad_cells(passes, plan)
-        key = key_col if key_col in rg.columns else ops.rb
-        valid = rg.valid
-        if bad.any() and not final:
-            valid = valid & jnp.asarray(~bad)[:, None, None]
-        keys_out.append(rg.columns[key].reshape(-1))
-        counts_out.append(np.asarray(counts, dtype=np.int64).reshape(-1))
-        valid_out.append(valid.reshape(-1))
+        with span("recovery.round", round=rnd, final=int(final)):
+            plan, passes, layouts = _round_pass(ops, rels, plan,
+                                                base_salt + rnd, final)
+            tuples += ops.tuples_read(rels, plan)
+            rg = layouts["r"]
+            counts = kops.fused_per_r_counts(
+                rg.columns[ops.rb], rg.valid, layouts["s"].columns[ops.sb],
+                layouts["s"].columns[ops.sc], layouts["s"].valid,
+                layouts["t"].columns[ops.tc], layouts["t"].valid,
+                use_kernel=use_kernel)                        # [hp, u, Cr]
+            bad = ops.bad_cells(passes, plan)
+            key = key_col if key_col in rg.columns else ops.rb
+            valid = rg.valid
+            if bad.any() and not final:
+                valid = valid & jnp.asarray(~bad)[:, None, None]
+            keys_out.append(rg.columns[key].reshape(-1))
+            counts_out.append(
+                to_host("counts", counts).astype(np.int64).reshape(-1))
+            valid_out.append(valid.reshape(-1))
         rounds = rnd + 1
         if final or not bad.any():
             break
-        rels = ops.residual(rels, passes, bad, plan)
+        with span("recovery.residual", round=rnd):
+            rels = ops.residual(rels, passes, bad, plan)
         plan = grown(plan, growth)
     keys = jnp.concatenate(keys_out)
     counts = np.concatenate(counts_out)
     valid = jnp.concatenate(valid_out)
-    total = int(counts[np.asarray(valid)].sum())
+    total = int(counts[to_host("valid", valid)].sum())
     return PerRResult(count=np.int64(total), overflowed=jnp.asarray(False),
                       tuples_read=np.int64(tuples), rounds=rounds,
                       keys=keys, counts=counts, valid=valid)

@@ -28,6 +28,8 @@ from typing import Callable, Mapping
 import jax
 import jax.numpy as jnp
 
+from repro.core.spans import to_host
+
 
 # The canonical padding sentinel for invalid relation slots.  Every layer
 # that fills dead slots (``sentinel_fill``, ``partition.bucketize``,
@@ -118,8 +120,8 @@ class Relation:
         to the column's capacity.  The planner's scan-free replacement
         for host ``np.unique`` passes."""
         from repro.core import sketches
-        est = int(round(float(sketches.fm_estimate(
-            self.distinct_sketch(col)))))
+        est = int(round(float(to_host("sketch", sketches.fm_estimate(
+            self.distinct_sketch(col))))))
         return max(1, min(est, self.capacity))
 
     # -- ingest --------------------------------------------------------------
@@ -221,6 +223,11 @@ class Relation:
 
     def mask_where(self, keep: jnp.ndarray) -> "Relation":
         return Relation(dict(self.columns), self.valid & keep)
+
+
+def live_rows(*rels: Relation) -> tuple[int, ...]:
+    """Live rows of each relation, read to the host in one sync."""
+    return tuple(map(int, to_host("rows", tuple(r.n for r in rels))))
 
 
 def sentinel_fill(rel: Relation, sentinel: int = SENTINEL) -> Relation:

@@ -38,6 +38,7 @@ import numpy as np
 from repro.core import plan_ir, planner, recovery, sketches
 from repro.core.query import STAR_FACT_RATIO, Classification, Query
 from repro.core.results import JoinResult
+from repro.core.spans import span
 from repro.perfmodel import HW, PLASTICINE, Calibration
 
 
@@ -229,8 +230,30 @@ class JoinSession:
                              "multiway engine) or 'cascade' (force the "
                              "binary cascade)")
         t0 = time.perf_counter()
+        with span("session.plan") as plan_span:
+            qp, cache_hit = self._plan_for(query, m_budget, per_r, key_col,
+                                           plan, strategy, classification)
+            plan_span.set_metadata(cache_hit=int(cache_hit))
+        plan_s = time.perf_counter() - t0
+
+        t1 = time.perf_counter()
+        res = plan_ir.execute_plan(qp, dict(query.relations))
+        exec_s = time.perf_counter() - t1
+        return QueryResult(
+            count=np.int64(res.count), overflowed=bool(res.overflowed),
+            tuples_read=np.int64(res.tuples_read), rounds=int(res.rounds),
+            kind=qp.kind, strategy=qp.strategy, cache_hit=cache_hit,
+            plan_s=plan_s, exec_s=exec_s, plan=qp, per_r=res.per_r,
+            steps=res.step_stats)
+
+    def _plan_for(self, query: Query, m_budget, per_r, key_col, plan,
+                  strategy, classification
+                  ) -> tuple[plan_ir.QueryPlan, bool]:
+        """The plan :meth:`execute` walks, and whether the cache held it:
+        the live cardinalities (one host read), then the cached or freshly
+        verified plan."""
         m_budget = self.m_budget if m_budget is None else m_budget
-        cards = {name: int(rel.n) for name, rel in query.relations.items()}
+        cards = query.live_cards()
         per_r_name = self._resolve_per_r(query, cards, per_r)
         if plan is not None:
             cls_ = classification or query.classify(
@@ -250,22 +273,9 @@ class JoinSession:
                 name: frozenset(rel.columns)
                 for name, rel in query.relations.items()})
             check_widths(qp, cards)
-            cache_hit = False
-        else:
-            qp, cache_hit = self._plan(query, cards, m_budget, strategy,
-                                       classification, per_r_name,
-                                       key_col)
-        plan_s = time.perf_counter() - t0
-
-        t1 = time.perf_counter()
-        res = plan_ir.execute_plan(qp, dict(query.relations))
-        exec_s = time.perf_counter() - t1
-        return QueryResult(
-            count=np.int64(res.count), overflowed=bool(res.overflowed),
-            tuples_read=np.int64(res.tuples_read), rounds=int(res.rounds),
-            kind=qp.kind, strategy=qp.strategy, cache_hit=cache_hit,
-            plan_s=plan_s, exec_s=exec_s, plan=qp, per_r=res.per_r,
-            steps=res.step_stats)
+            return qp, False
+        return self._plan(query, cards, m_budget, strategy, classification,
+                          per_r_name, key_col)
 
     # -- standing queries --------------------------------------------------
 
@@ -313,7 +323,7 @@ class JoinSession:
         """
         from repro.core import distributed
         t0 = time.perf_counter()
-        cards = {name: int(rel.n) for name, rel in query.relations.items()}
+        cards = query.live_cards()
         cls_ = classification or query.classify(
             cards, star_fact_ratio=self.star_fact_ratio)
         binding = query.bind(cls_)

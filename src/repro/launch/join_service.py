@@ -13,9 +13,10 @@ top of the declarative join engine:
     :class:`ServiceOverloaded` immediately (backpressure — callers retry
     or shed, the service never buffers unboundedly).
   * **Waves.**  The pump drains up to ``wave_size`` requests, groups plain
-    executes per tenant and runs them through
-    ``JoinSession.execute_many`` — structurally repeated queries in a
-    wave share the tenant session's log-bucketed plan cache — and applies
+    executes per tenant and runs them in turn on the tenant's session, as
+    ``JoinSession.execute_many`` would (one failure fails the tenant's
+    whole wave) — structurally repeated queries in a wave share the
+    session's log-bucketed plan cache — and applies
     ingests in admission order (each ``Relation.append`` synchronously
     drives the registered standing queries' delta plans).
   * **Tenancy.**  Each tenant name owns one ``JoinSession`` (plan cache,
@@ -32,6 +33,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import itertools
 import json
 import queue
 import threading
@@ -41,6 +43,7 @@ from concurrent.futures import Future
 from repro.core.query import Query
 from repro.core.relation import Relation
 from repro.core.session import JoinSession
+from repro.core.spans import span
 
 
 class ServiceOverloaded(RuntimeError):
@@ -82,6 +85,7 @@ class _Request:
     handle: object = None        # StandingQuery for snapshot
     strategy: str | None = None
     admitted: float = 0.0
+    seq: int = 0                 # admission order, for spans
 
 
 class _Tenant:
@@ -105,11 +109,13 @@ class JoinService:
         self._running = False
         self.waves = 0
         self.rejected = 0
+        self._seq = itertools.count()
 
     # -- admission (any thread) -------------------------------------------
 
     def _admit(self, req: _Request) -> Future:
         req.admitted = time.perf_counter()
+        req.seq = next(self._seq)
         try:
             self._queue.put_nowait(req)
         except queue.Full:
@@ -158,10 +164,17 @@ class JoinService:
         tr = getattr(res, "tuples_read", None)
         ten.tuples_read.record(0 if tr is None else int(tr))
 
+    def _execute(self, ten: _Tenant, req: _Request, strategy):
+        """One plain execute under a ``repro.service.request`` span, which
+        carries the admission-to-start wait in microseconds."""
+        queued_us = int((time.perf_counter() - req.admitted) * 1e6)
+        with span("service.request", req=req.seq, queued_us=queued_us):
+            return ten.session.execute(req.query, strategy=strategy)
+
     def pump(self) -> int:
         """Drain one wave (≤ wave_size requests): group executes per
-        tenant through ``execute_many``, apply the rest in admission
-        order.  Returns the number of requests served."""
+        tenant (answered together once all have run), apply the rest in
+        admission order.  Returns the number of requests served."""
         wave: list[_Request] = []
         while len(wave) < self.wave_size:
             try:
@@ -180,9 +193,8 @@ class JoinService:
         for tenant, reqs in by_tenant.items():
             ten = self._tenant(tenant)
             try:
-                results = ten.session.execute_many(
-                    [r.query for r in reqs],
-                    strategy=reqs[0].strategy)
+                results = [self._execute(ten, r, reqs[0].strategy)
+                           for r in reqs]
             except Exception as e:          # noqa: BLE001 — fail the wave's futures
                 for r in reqs:
                     r.future.set_exception(e)

@@ -6,8 +6,11 @@ the ground truth every JAX/Pallas path is checked against.
 
 from __future__ import annotations
 
+import dataclasses
+import glob
 import pathlib
 import sys
+import warnings
 from collections import Counter, defaultdict
 
 import numpy as np
@@ -117,3 +120,52 @@ def oracle_distinct_join_pairs(rb, ra, sb, sc, tc, td) -> int:
 @pytest.fixture
 def rng():
     return np.random.default_rng(1234)
+
+
+# --------------------------------------------------------------------------
+# program spans from a recorded profiler trace
+# --------------------------------------------------------------------------
+
+@dataclasses.dataclass
+class RecordedSpan:
+    name: str
+    start: int               # ns, the trace's host clock
+    end: int
+    args: dict
+
+
+def read_spans(log_dir) -> list[RecordedSpan]:
+    """The ``repro.`` spans of the newest trace under ``log_dir``, in start
+    order (outer before inner at equal starts)."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True))[-1]
+    out = []
+    with warnings.catch_warnings():
+        # event stats are a builtin type without __module__ (jax 0.9)
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("repro."):
+                        s = int(e.start_ns)
+                        out.append(RecordedSpan(e.name, s,
+                                                s + int(e.duration_ns),
+                                                dict(e.stats)))
+    return sorted(out, key=lambda sp: (sp.start, -sp.end))
+
+
+@pytest.fixture
+def record_spans(tmp_path):
+    """``record_spans(fn)`` runs ``fn()`` under the profiler and returns
+    its result and the ``repro.`` spans recorded meanwhile."""
+    import jax
+
+    def record(fn):
+        log_dir = tmp_path / f"trace{len(list(tmp_path.iterdir()))}"
+        jax.profiler.start_trace(str(log_dir))
+        try:
+            out = fn()
+        finally:
+            jax.profiler.stop_trace()
+        return out, read_spans(log_dir)
+    return record

@@ -160,7 +160,7 @@ def test_warm_execute_plan_is_scan_free(rng, monkeypatch):
 
 
 # --------------------------------------------------------------------------
-# buffer arena: multi-consumer intermediates and profile mode
+# buffer arena: multi-consumer intermediates; plan-step spans
 # --------------------------------------------------------------------------
 
 def _oracle_pairs(keys_a, keys_b) -> int:
@@ -207,9 +207,10 @@ def test_arena_keeps_multi_consumer_intermediate(rng):
     assert not res.overflowed
 
 
-def test_profile_mode_fills_wall_and_matches_default(rng):
-    """profile=True serializes the overlap for attribution: counts stay
-    identical, wall_s is populated per step, dispatch_s is recorded."""
+def test_plan_steps_record_one_span_each(rng, record_spans):
+    """Each step of the 4-relation cascade runs under one
+    ``repro.plan.step`` span, in plan order, naming its op and output;
+    recording the spans leaves the count as it was."""
     rels = [make_rel(rng, 500, (c1, c2), 50)[0]
             for c1, c2 in (("a", "b"), ("b", "c"), ("c", "d"), ("d", "e"))]
     names = [f"r{i}" for i in range(1, 5)]
@@ -218,14 +219,14 @@ def test_profile_mode_fills_wall_and_matches_default(rng):
     sess = JoinSession(m_budget=128)
     qp = sess.execute(q, strategy="cascade").plan
     fast = plan_ir.execute_plan(qp, dict(q.relations))
-    prof = plan_ir.execute_plan(qp, dict(q.relations), profile=True)
-    assert int(prof.count) == int(fast.count)
-    assert all(s.wall_s > 0.0 for s in prof.step_stats)
-    assert all(s.wall_s == 0.0 for s in fast.step_stats
-               if s.op == "binary")
-    assert all(s.dispatch_s >= 0.0 for s in prof.step_stats)
-    # stats keep their aggregation contract under the new fields
-    assert sum(s.tuples_read for s in prof.step_stats) == prof.tuples_read
+    traced, spans = record_spans(
+        lambda: plan_ir.execute_plan(qp, dict(q.relations)))
+    steps = [sp.args for sp in spans if sp.name == "repro.plan.step"]
+    assert [(a["i"], a["op"], a["out"]) for a in steps] == [
+        (i, st.op, st.out) for i, st in enumerate(qp.steps)]
+    assert len(qp.steps) >= 3
+    assert int(traced.count) == int(fast.count)
+    assert sum(s.tuples_read for s in traced.step_stats) == traced.tuples_read
 
 
 # --------------------------------------------------------------------------

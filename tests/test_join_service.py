@@ -2,7 +2,7 @@
 
 The service is the async front end over ``JoinSession`` — requests go
 through a bounded queue (full → ``ServiceOverloaded``), waves group plain
-executes per tenant through ``execute_many`` (shared plan cache), ingest
+executes per tenant on one session (shared plan cache), ingest
 requests drive standing-query delta plans synchronously, and per-tenant
 power-of-two histograms export latency/rounds/tuples_read.
 """
