@@ -155,16 +155,33 @@ def _bucket_multiplicity(table, probes):
 
     table: [B, Ct] sentinel-masked keys; probes: [B, Cp].  Returns [B, Cp]
     int32 — for each probe, how many equal keys its OWN bucket row holds.
-    Sorted rows + two binary searches per probe (O(Cp log Ct) per bucket,
-    vs O(Cp·Ct) for the all-pairs compare the SIMD kernels use — the right
-    realization of the same per-bucket math for a scalar/CPU backend).
+
+    A sort-merge count: each row's table and probe keys are sorted
+    together by (key, slot), where a table entry's slot is -1 and a
+    probe's is its index, so inside every run of equal keys the table
+    entries come first.  A probe's count is then the running number
+    of table entries at the probe minus that number where its run starts.
+    A second sort by slot puts the counts back in probe order.  Two sorts
+    of Ct + Cp elements per row and no gather: on a TPU a binary search
+    per probe pays a gather's latency at every search step.
     """
-    srt = jnp.sort(table, axis=-1)
-    lo = jax.vmap(lambda t, p: jnp.searchsorted(t, p, side="left"))(
-        srt, probes)
-    hi = jax.vmap(lambda t, p: jnp.searchsorted(t, p, side="right"))(
-        srt, probes)
-    return (hi - lo).astype(jnp.int32)
+    b, ct = table.shape
+    cp = probes.shape[-1]
+    keys = jnp.concatenate([table, probes], axis=-1)
+    slot = jnp.concatenate(
+        [jnp.full((b, ct), -1, jnp.int32),
+         jnp.broadcast_to(jnp.arange(cp, dtype=jnp.int32), (b, cp))],
+        axis=-1)
+    keys, slot = jax.lax.sort((keys, slot), dimension=1, num_keys=2)
+    is_table = (slot < 0).astype(jnp.int32)
+    seen = jnp.cumsum(is_table, axis=-1)          # table entries so far
+    run_start = jnp.concatenate(
+        [jnp.ones((b, 1), bool), keys[:, 1:] != keys[:, :-1]], axis=-1)
+    before_run = jax.lax.cummax(
+        jnp.where(run_start, seen - is_table, 0), axis=1)
+    _, counts = jax.lax.sort((slot, seen - before_run), dimension=1,
+                             num_keys=1)
+    return counts[:, ct:]
 
 
 def _fused_linear_ref(rb, sb, sc, tc):
@@ -174,7 +191,8 @@ def _fused_linear_ref(rb, sb, sc, tc):
     multiplicity (probing the matching (H, h) bucket) times its T
     multiplicity (probing the matching g bucket), then per-(H, h) partial
     sums — identical per-bucket semantics to the scan driver, realized with
-    sorted-bucket probes instead of all-pairs compares.
+    sort-merge counts (``_bucket_multiplicity``) instead of all-pairs
+    compares.
     """
     hp, u, cr = rb.shape
     _, gp, _, cs = sb.shape
@@ -347,8 +365,8 @@ def _fused_cyclic_ref(ra, rb, sb, sc, tc, ta):
 def _fused_star_ref(rb, sb, sc, tc):
     """rb [uh,Cr], sb/sc [ch,uh,ug,Cs], tc [ug,Ct] -> [uh,ug] int32.
 
-    Same sorted-bucket-probe scheme as ``_fused_linear_ref``: each fact slot
-    probes the R bucket of its row and the T bucket of its column.
+    Same sort-merge count as ``_fused_linear_ref``: each fact slot is
+    counted against the R bucket of its row and the T bucket of its column.
     """
     uh, cr = rb.shape
     ch, _, ug, cs = sb.shape

@@ -2,7 +2,9 @@
 shapes/dtypes.  Counts are integers, so checks are exact equality."""
 
 import functools
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -247,3 +249,75 @@ def test_cyclic_pairidx_ref_working_set_is_one_h_row():
     query's plan over 200k edges needs ~13.8 GB of scratch on a 16 GB v5e."""
     one, eight = _cyclic_pairidx_temp_bytes(1), _cyclic_pairidx_temp_bytes(8)
     assert eight < 2 * one
+
+
+# --------------------------------------------------------------------------
+# fused jnp roots: the sort-merge bucket count
+# --------------------------------------------------------------------------
+
+_KEY_FLOOR = -(1 << 30)
+
+
+def _multiplicity_rows(case, rng):
+    """(table [B, Ct], probes [B, Cp]) int32 rows for one named case."""
+    t_sent, p_sent = ops._SENT["t"], ops._SENT["s"]
+    if case == "duplicates":
+        return (rng.integers(0, 3, (4, 97)), rng.integers(0, 4, (4, 203)))
+    if case == "all_sentinel":
+        table = rng.integers(0, 5, (3, 40))
+        probes = rng.integers(0, 5, (3, 60))
+        table[1], probes[1] = t_sent, p_sent        # a wholly padded row
+        table[2] = t_sent                           # nothing to match
+        probes[0, ::2] = p_sent
+        return table, probes
+    if case == "ct1_cp1":
+        return rng.integers(0, 2, (5, 1)), rng.integers(0, 2, (5, 1))
+    if case == "ct1":
+        return rng.integers(0, 3, (2, 1)), rng.integers(0, 3, (2, 33))
+    if case == "cp1":
+        return rng.integers(0, 3, (2, 45)), rng.integers(0, 3, (2, 1))
+    if case == "all_match":
+        table = np.full((3, 17), 7)
+        table[:, ::3] = 9
+        return table, rng.choice([7, 9], (3, 29))
+    if case == "near_key_floor":
+        table = _KEY_FLOOR + rng.integers(0, 6, (3, 50))
+        probes = _KEY_FLOOR + rng.integers(0, 6, (3, 70))
+        table[:, -5:], probes[:, -3:] = t_sent, p_sent
+        return table, probes
+    assert case == "odd_lengths"
+    return (rng.integers(-50, 50, (7, 131)), rng.integers(-50, 50, (7, 389)))
+
+
+@pytest.mark.parametrize("case", ["duplicates", "all_sentinel", "ct1_cp1",
+                                  "ct1", "cp1", "all_match",
+                                  "near_key_floor", "odd_lengths"])
+def test_bucket_multiplicity_matches_numpy(case):
+    """The fused roots' per-probe count equals the all-pairs compare over
+    each bucket row, sentinels included (table and probe sentinels differ,
+    so padded slots never match)."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    table, probes = (np.asarray(x, np.int32)
+                     for x in _multiplicity_rows(case, rng))
+    want = np.sum(table[:, None, :] == probes[:, :, None], axis=-1)
+    got = np.asarray(ops._bucket_multiplicity(jnp.asarray(table),
+                                              jnp.asarray(probes)))
+    assert got.dtype == np.int32 and got.shape == probes.shape
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("kind", ["linear", "star"])
+def test_fused_jnp_roots_lower_without_gather_scatter_or_loop(kind):
+    """The jnp fused roots count with sorts and scans alone: no gather,
+    scatter or while loop, each of which costs the chip a memory round
+    trip per element or per search step."""
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    b1 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bool_)
+    if kind == "linear":
+        fn, r, s, t = ops.fused_count3_linear, (2, 3, 40), (2, 3, 3, 24), (3, 56)
+    else:
+        fn, r, s, t = ops.fused_count3_star, (3, 40), (2, 3, 4, 24), (4, 56)
+    text = fn.lower(i32(r), b1(r), i32(s), i32(s), b1(s), i32(t), b1(t),
+                    use_kernel=False).as_text()
+    assert "stablehlo.sort" in text
+    assert not re.findall(r"stablehlo\.(gather|scatter|while)\b", text)
