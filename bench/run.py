@@ -6,14 +6,14 @@
 A cell names a configuration (``bench/configs/<config>.json``: its sizes,
 the generator module under ``bench/gen/``, its queries, its guarantee and
 its plain reference under ``bench/refs/``) and a traffic mix
-(``bench/traffic/<traffic>.json``).  A run makes the tables from ``--seed``
-on the host, puts them on the chip, starts
-``launch.join_service.JoinService`` with its pump thread, warms up the
-cell's own query shapes, and then drives the service as one
-closed-loop client for ``--seconds``: it sends its next request when the
-previous one is answered, and the request in flight at the deadline
-completes and counts.  Every answer of the window is then compared
-with the reference.
+(``bench/traffic/<traffic>.json``), whose ``op`` names the driver
+``bench/drivers/<op>.py`` (the interface is in ``drivers/__init__.py``).
+A run makes the tables from ``--seed`` on the host; the driver puts them
+on the chip, starts the service and warms up the cell's own shapes, which
+ends the set-up.  The driver then drives the service for ``--seconds``:
+the request in flight at the deadline completes and counts.  Once the
+service is stopped the driver compares every answer of the window with
+the reference.
 
 ``--trace 0`` prints the cell's end-to-end metrics.  ``--trace 1`` profiles
 the first seconds of the window and prints the cell's per-layer metrics,
@@ -34,17 +34,12 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
-import math  # noqa: E402
 import os  # noqa: E402
 import pathlib  # noqa: E402
-import shutil  # noqa: E402
 import sys  # noqa: E402
-
-import numpy as np  # noqa: E402
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -52,18 +47,14 @@ sys.path.insert(0, str(ROOT / "src"))
 sys.path.insert(0, str(BENCH))
 
 import trace_reduce  # noqa: E402
+from harness import STREAM_DATA, Req, Tracer, log, rng_for  # noqa: E402
 
-TENANT = "bench"
-TRACE_SECONDS = 5.0      # profiled head of a --trace 1 window
-STREAM_DATA = 0
+TRAFFIC = BENCH / "traffic"
+DRIVERS = BENCH / "drivers"
 
 
 class NoDevice(RuntimeError):
     """No TPU, or fewer chips than the cell asks for."""
-
-
-def log(msg: str) -> None:
-    print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
 
 def load_json(path) -> dict:
@@ -78,10 +69,6 @@ def load_module(path: pathlib.Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
-
-
-def rng_for(seed: int, stream: int) -> np.random.Generator:
-    return np.random.default_rng([seed % (1 << 64), stream])
 
 
 # --------------------------------------------------------------------------
@@ -111,7 +98,7 @@ def load_cell(name: str, spec: dict | None = None) -> Cell:
     w = cells[name]
     return Cell(name=name, chips=int(w["chips"]),
                 config=load_json(BENCH / "configs" / f"{w['config']}.json"),
-                traffic=load_json(BENCH / "traffic" / f"{w['traffic']}.json"),
+                traffic=load_json(TRAFFIC / f"{w['traffic']}.json"),
                 end_to_end=_for_cell(spec["end_to_end"], name),
                 per_layer=_for_cell(spec["per_layer"], name))
 
@@ -168,7 +155,7 @@ class Compiles:
 
 
 # --------------------------------------------------------------------------
-# data and queries
+# data, reference and driver
 # --------------------------------------------------------------------------
 
 def generator(cfg: dict):
@@ -179,89 +166,15 @@ def reference(cfg: dict):
     return load_module(BENCH / "refs" / f"{cfg.get('reference', 'acyclic')}.py")
 
 
-def relations(cfg: dict, tables: dict) -> dict:
-    from repro.core import Relation
-    caps = cfg.get("capacity", {})
-    return {name: Relation.from_arrays(capacity=caps.get(name), **cols)
-            for name, cols in tables.items()}
-
-
-def make_query(qspec: dict, rels: dict):
-    from repro.core import Query
-    return Query(relations={a: rels[t] for a, t in qspec["relations"].items()},
-                 predicates=[tuple(p) for p in qspec["predicates"]])
-
-
-# --------------------------------------------------------------------------
-# the closed-loop window
-# --------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class Req:
-    t0: float
-    t1: float | None = None
-    result: object = None
-    error: str | None = None
-    traced: bool = False
-
-
-class Tracer:
-    """Profiles the window from its start until the first request that
-    ends ``TRACE_SECONDS`` in; requests begun before then carry a
-    ``bench.`` span."""
-
-    def __init__(self, log_dir: pathlib.Path | None):
-        self.log_dir = log_dir
-        self.on = False
-        self.until = 0.0
-
-    def start(self, t0: float) -> None:
-        if self.log_dir is None:
-            return
-        import jax
-        shutil.rmtree(self.log_dir, ignore_errors=True)
-        self.log_dir.mkdir(parents=True)
-        jax.profiler.start_trace(str(self.log_dir))
-        self.on = True
-        self.until = t0 + TRACE_SECONDS
-
-    def span(self, name: str):
-        """A ``bench.<name>`` span while the profiler runs, else nothing."""
-        if not self.on:
-            return contextlib.nullcontext()
-        import jax
-        return jax.profiler.TraceAnnotation(f"bench.{name}")
-
-    def after(self, now: float) -> None:
-        """Stop once a request has ended past the traced head."""
-        if not self.on or now < self.until:
-            return
-        import jax
-        jax.profiler.stop_trace()
-        self.on = False
-
-    def close(self) -> None:
-        self.after(math.inf)
-
-
-def query_window(svc, query, seconds: float, tracer: Tracer) -> list[Req]:
-    """One client: send the query, wait for its count, repeat."""
-    start = time.perf_counter()
-    tracer.start(start)
-    deadline = start + seconds
-    out: list[Req] = []
-    while not out or time.perf_counter() < deadline:
-        req = Req(t0=time.perf_counter(), traced=tracer.on)
-        with tracer.span("query"):
-            try:
-                req.result = svc.submit(TENANT, query).result()
-            except Exception as e:  # noqa: BLE001 - a failed answer is counted
-                req.error = f"{type(e).__name__}: {e}"
-        req.t1 = time.perf_counter()
-        out.append(req)
-        tracer.after(req.t1)
-    tracer.close()
-    return out
+def load_driver(op: str):
+    """``drivers/<op>.py``; an ``op`` with no driver ends the run."""
+    path = DRIVERS / f"{op}.py"
+    if not path.is_file():
+        present = sorted(p.stem for p in DRIVERS.glob("*.py")
+                         if p.stem != "__init__")
+        raise SystemExit(f"unknown traffic op {op!r}; drivers present in "
+                         f"{DRIVERS}: {present}")
+    return load_module(path)
 
 
 # --------------------------------------------------------------------------
@@ -294,7 +207,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     """Set up, run the window, check every answer; return the result
     line as a dict.  Raises :class:`NoDevice` before any work."""
     t_start = T_START if t_start is None else t_start
-    from repro.launch.join_service import JoinService
+    driver = load_driver(cell.traffic["op"])
     devs = devices(cell.chips, allow_cpu)
 
     log(f"compile cache: {enable_compile_cache()}")
@@ -303,24 +216,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     kind = devs[0].device_kind
     if kind not in peaks_all and not allow_cpu:
         raise SystemExit(f"bench/peaks.json has no row for {kind!r}")
-    cfg, traffic = cell.config, cell.traffic
-    if traffic["op"] != "query":
-        raise SystemExit(f"unknown traffic op {traffic['op']!r}")
-    qspec = cfg["queries"][traffic["query"]]
+    cfg = cell.config
     gen, ref = generator(cfg), reference(cfg)
     tables = gen.make(cfg, rng_for(seed, STREAM_DATA))
     base_rows = {n: len(next(iter(t.values()))) for n, t in tables.items()}
-    rels = relations(cfg, tables)
-    query = make_query(qspec, rels)
-    svc = JoinService(max_queue=64, wave_size=8, m_budget=cfg["m_budget"])
-    svc.start()
     tracer = Tracer(BENCH / "traces" / cell.name if trace else None)
+    state = driver.open(cell, devs, tables, seed)
     try:
-        for _ in range(traffic.get("warmup", 1)):
-            svc.submit(TENANT, query).result()
         setup_s = time.perf_counter() - t_start
         c0 = compiles.snapshot()
-        reqs = query_window(svc, query, seconds, tracer)
+        reqs = driver.window(state, seconds, tracer)
         c1 = compiles.snapshot()
         built, loaded = c1[0] - c0[0], c1[1] - c0[1]
         log(f"window: {len(reqs)} requests; compilations inside the window "
@@ -333,7 +238,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                    for d in devs)
     finally:
         tracer.close()
-        svc.stop()
+        driver.close(state)
     done = [r for r in reqs if r.t1 is not None]
     window_s = max(r.t1 for r in done) - min(r.t0 for r in reqs)
     run = Run(cell=cell, setup_s=setup_s, window_s=window_s,
@@ -346,7 +251,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             ops, spans, trace_reduce.load_layers(BENCH / "layers.json"))
 
     # -- the check, once the window has closed and the peak is read --------
-    checks = check_queries(ref, tables, qspec, reqs)
+    checks = driver.check(state, ref, reqs)
     correct = all(v["value"] <= v["limit"] for v in checks.values())
     metrics = cell.per_layer if trace else cell.end_to_end
     values = {}
@@ -371,20 +276,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
 def read_metric(name: str, run: Run):
     return load_module(BENCH / "metrics" / f"{name}.py").read(run)
-
-
-# --------------------------------------------------------------------------
-# correctness: every answer of the window against the plain reference
-# --------------------------------------------------------------------------
-
-def check_queries(ref, tables, qspec, reqs: list[Req]) -> dict:
-    want = ref.count(tables, qspec)
-    gap = max((abs(int(r.result.count) - want) for r in reqs
-               if r.result is not None), default=0)
-    unanswered = sum(1 for r in reqs if r.result is None)
-    log(f"check: {len(reqs) - unanswered} answers, reference count {want}")
-    return {"count_gap": {"value": gap, "limit": 0},
-            "unanswered": {"value": unanswered, "limit": 0}}
 
 
 # --------------------------------------------------------------------------

@@ -6,6 +6,7 @@ import pytest
 
 import control
 import run as bench
+from harness import Req
 
 SMALL = {"kron.fofof": {"scale": 11}, "ssb.star5": {"sf": 0.01}}
 
@@ -24,6 +25,8 @@ def test_control_fails_the_comparison(name, seed):
     qspec = cfg["queries"][cell.traffic["query"]]
     tables = bench.generator(cfg).make(
         cfg, bench.rng_for(seed, bench.STREAM_DATA))
-    checks = bench.check_queries(bench.reference(cfg), tables, qspec,
-                                 [bench.Req(t0=0.0, t1=1.0, result=_Res())])
+    query = bench.load_driver("query")
+    state = query.State(svc=None, query=None, tables=tables, qspec=qspec)
+    checks = query.check(state, bench.reference(cfg),
+                         [Req(t0=0.0, t1=1.0, result=_Res())])
     assert checks["count_gap"]["value"] > checks["count_gap"]["limit"]
